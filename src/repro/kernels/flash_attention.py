@@ -70,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         window: int = 0, block_q: int = 128,
                         block_k: int = 128,
-                        interpret: bool = True) -> jnp.ndarray:
+                        *, interpret: bool) -> jnp.ndarray:
     """q [B,Tq,KV,G,hd]; k/v [B,Tk,KV,hd] -> [B,Tq,KV,G,hd] (causal)."""
     b, tq, kvh, g, hd = q.shape
     tk = k.shape[1]

@@ -8,20 +8,16 @@ to fake 512 host devices; smoke tests and benchmarks see 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_host_mesh"]
+__all__ = ["make_production_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2 pods for the multi-pod dry-run."""
+    """16x16 = 256 chips per pod; 2 pods for the multi-pod dry-run.
+
+    Axes are ``Auto``: the models place work through sharding annotations
+    and ``shard_map``, and leave propagation to the compiler."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over whatever devices exist (tests / CPU examples)."""
-    n = len(jax.devices())
-    if data * model > n:
-        data, model = n, 1
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

@@ -22,16 +22,24 @@ from __future__ import annotations
 import io
 import json
 import zlib
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..core.client import NotFound
 from ..core.fs import CfsMount
 
-__all__ = ["CheckpointManager", "tensor_to_bytes", "bytes_to_tensor"]
+__all__ = ["CheckpointManager", "InjectedCrash", "tensor_to_bytes",
+           "bytes_to_tensor"]
 
 _MAGIC = b"RPT1"
+
+
+class InjectedCrash(RuntimeError):
+    """A crash injected on purpose (``crash_at`` / ``crash_after``).
+
+    Launchers resume from the last checkpoint on this class only; any other
+    error, a device fault among them, propagates."""
 
 
 def tensor_to_bytes(arr: np.ndarray) -> bytes:
@@ -45,33 +53,19 @@ def bytes_to_tensor(data: bytes) -> np.ndarray:
     assert data[:4] == _MAGIC, "bad tensor file"
     hlen = int.from_bytes(data[4:8], "little")
     header = json.loads(data[8 : 8 + hlen].decode())
-    raw = data[8 + hlen :]
-    return np.frombuffer(raw, dtype=np.dtype(header["dtype"])).reshape(
-        header["shape"]).copy()
+    return np.frombuffer(data, dtype=np.dtype(header["dtype"]),
+                         offset=8 + hlen).reshape(header["shape"]).copy()
 
 
-def _flatten(tree: Any, prefix: str = "") -> List[Tuple[str, np.ndarray]]:
+def _leaf_name(path) -> str:
+    return "~".join(
+        str(p.key) if hasattr(p, "key") else str(p.idx) for p in path)
+
+
+def _flatten(tree: Any) -> List[Tuple[str, np.ndarray]]:
     import jax
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    out = []
-    for path, leaf in flat:
-        name = "~".join(
-            str(p.key) if hasattr(p, "key") else str(p.idx) for p in path)
-        out.append((name, np.asarray(leaf)))
-    return out
-
-
-def _unflatten(tree_like: Any, leaves: Dict[str, np.ndarray]) -> Any:
-    import jax
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree_like)
-    ordered = []
-    for path, leaf in flat:
-        name = "~".join(
-            str(p.key) if hasattr(p, "key") else str(p.idx) for p in path)
-        arr = leaves[name]
-        ordered.append(arr.astype(leaf.dtype) if hasattr(leaf, "dtype")
-                       else arr)
-    return jax.tree_util.tree_unflatten(treedef, ordered)
+    return [(_leaf_name(path), np.asarray(leaf)) for path, leaf in flat]
 
 
 class CheckpointManager:
@@ -96,7 +90,6 @@ class CheckpointManager:
         manifest: Dict[str, Any] = {"step": step, "tensors": {}}
         writes = 0
         for name, arr in _flatten(tree):
-            payload = tensor_to_bytes(arr)
             nsh = self.shards if (arr.ndim > 0 and arr.shape[0] >= self.shards
                                   and arr.shape[0] % self.shards == 0) else 1
             if nsh > 1:
@@ -104,7 +97,7 @@ class CheckpointManager:
                 parts = [tensor_to_bytes(arr[i * per : (i + 1) * per])
                          for i in range(nsh)]
             else:
-                parts = [payload]
+                parts = [tensor_to_bytes(arr)]
             entry = {"shards": [], "dtype": str(arr.dtype),
                      "shape": list(arr.shape)}
             for k, part in enumerate(parts):
@@ -112,7 +105,8 @@ class CheckpointManager:
                 self.mnt.write_file(path, part)
                 writes += 1
                 if crash_after is not None and writes >= crash_after:
-                    raise RuntimeError("injected crash during checkpoint save")
+                    raise InjectedCrash(
+                        "injected crash during checkpoint save")
                 entry["shards"].append(
                     {"path": path, "bytes": len(part),
                      "crc32": zlib.crc32(part) & 0xFFFFFFFF})
@@ -120,7 +114,7 @@ class CheckpointManager:
         # data fully durable -> manifest -> commit pointer (atomic order)
         self.mnt.write_file(f"{d}/MANIFEST", json.dumps(manifest).encode())
         if crash_after is not None and writes + 1 >= crash_after:
-            raise RuntimeError("injected crash before LATEST commit")
+            raise InjectedCrash("injected crash before LATEST commit")
         if self.mnt.exists(f"{self.base}/LATEST"):
             self.mnt.unlink(f"{self.base}/LATEST")
         self.mnt.write_file(f"{self.base}/LATEST", str(step).encode())
@@ -151,14 +145,23 @@ class CheckpointManager:
             steps = self.list_steps()
             return steps[-1] if steps else None
 
-    def restore(self, tree_like: Any, step: Optional[int] = None) -> Tuple[Any, int]:
+    def restore(self, tree_like: Any, step: Optional[int] = None,
+                put: Callable[[np.ndarray], Any] = lambda arr: arr
+                ) -> Tuple[Any, int]:
+        """Restore into the structure and dtypes of ``tree_like`` (arrays or
+        ``ShapeDtypeStruct``s).  Each leaf is read, CRC-checked and handed
+        to ``put`` (a device transfer, say) before the next one is read, so
+        the host holds one leaf at a time."""
+        import jax
         step = self.latest_step() if step is None else step
         if step is None:
             raise NotFound("no checkpoint")
         d = f"{self.base}/step_{step}"
         manifest = json.loads(self.mnt.read_file(f"{d}/MANIFEST").decode())
-        leaves: Dict[str, np.ndarray] = {}
-        for name, entry in manifest["tensors"].items():
+        flat, treedef = jax.tree_util.tree_flatten_with_path(tree_like)
+        leaves = []
+        for path, like in flat:
+            entry = manifest["tensors"][_leaf_name(path)]
             parts = []
             for sh in entry["shards"]:
                 data = self.mnt.read_file(sh["path"])
@@ -166,5 +169,8 @@ class CheckpointManager:
                     raise IOError(f"checksum mismatch in {sh['path']}")
                 parts.append(bytes_to_tensor(data))
             arr = parts[0] if len(parts) == 1 else np.concatenate(parts, 0)
-            leaves[name] = arr.reshape(entry["shape"])
-        return _unflatten(tree_like, leaves), step
+            arr = arr.reshape(entry["shape"])
+            if hasattr(like, "dtype"):
+                arr = arr.astype(like.dtype, copy=False)
+            leaves.append(put(arr))
+        return jax.tree_util.tree_unflatten(treedef, leaves), step

@@ -76,7 +76,10 @@ def init_opt_state(oc: OptConfig, params: Any) -> OptState:
     zeros = lambda p: jnp.zeros(p.shape, oc.moment_dtype)
     mu = jax.tree.map(zeros, params)
     nu = jax.tree.map(zeros, params)
-    master = (jax.tree.map(lambda p: p.astype(jnp.float32), params)
+    # a copy even for fp32 params: the train step donates params and
+    # optimizer state, and one buffer cannot be donated twice
+    master = (jax.tree.map(lambda p: jnp.array(p, jnp.float32, copy=True),
+                           params)
               if oc.master_weights else None)
     return OptState(jnp.zeros((), jnp.int32), mu, nu, master)
 

@@ -22,10 +22,17 @@ import numpy as np
 
 from ..configs.base import ArchConfig
 from ..models import get_model
-from ..storage.checkpoint import CheckpointManager
+from ..storage.checkpoint import CheckpointManager, InjectedCrash
 from ..storage.datapipe import ShardReader
 from . import optimizer as opt
 from .train_step import make_train_step
+
+
+def jit_train_step(cfg: ArchConfig, oc: opt.OptConfig):
+    """The jitted train step.  Params and optimizer state are donated: the
+    step's outputs reuse their buffers, so one copy of the training state is
+    on the device."""
+    return jax.jit(make_train_step(cfg, oc), donate_argnums=(0, 1))
 
 
 @dataclasses.dataclass
@@ -47,7 +54,7 @@ class Trainer:
         self.reader = reader
         self.api = get_model(cfg)
         self.ckpt = CheckpointManager(mount, tc.ckpt_base, shards=2)
-        self.step_fn = jax.jit(make_train_step(cfg, oc))
+        self.step_fn = jit_train_step(cfg, oc)
         key = jax.random.PRNGKey(seed)
         self.params = self.api.init(key, param_dtype)
         self.opt_state = opt.init_opt_state(oc, self.params)
@@ -65,17 +72,21 @@ class Trainer:
         self.ckpt.save(self.step, self.state_tree(), crash_after=crash_after)
 
     def resume(self) -> bool:
+        """Replace the state with the latest checkpoint's; False if there is
+        none.  The current state is dropped first and each leaf goes to the
+        device as it is read, so neither the device nor the host holds two
+        copies of the state (a failed restore leaves the trainer without
+        one)."""
         latest = self.ckpt.latest_step()
         if latest is None:
             return False
-        restored, step = self.ckpt.restore(self.state_tree())
-        self.params = jax.tree.map(jnp.asarray, restored["params"])
-        self.opt_state = opt.OptState(
-            step=jnp.asarray(restored["step"]),
-            mu=jax.tree.map(jnp.asarray, restored["mu"]),
-            nu=jax.tree.map(jnp.asarray, restored["nu"]),
-            master=(jax.tree.map(jnp.asarray, restored["master"])
-                    if restored["master"] is not None else None))
+        shapes = jax.eval_shape(self.state_tree)
+        self.params = self.opt_state = None
+        restored, step = self.ckpt.restore(shapes, put=jnp.asarray)
+        self.params = restored["params"]
+        self.opt_state = opt.OptState(step=restored["step"],
+                                      mu=restored["mu"], nu=restored["nu"],
+                                      master=restored["master"])
         self.step = step
         return True
 
@@ -96,7 +107,8 @@ class Trainer:
                      "loss": float(metrics["loss"]),
                      "grad_norm": float(metrics["grad_norm"])})
             if crash_at is not None and self.step == crash_at:
-                raise RuntimeError(f"injected trainer crash at step {self.step}")
+                raise InjectedCrash(
+                    f"injected trainer crash at step {self.step}")
             if self.step % self.tc.ckpt_every == 0:
                 self.save()
         return self.history

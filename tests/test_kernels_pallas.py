@@ -25,7 +25,8 @@ def test_flash_pallas_sweep(t, window, dtype, kv, g):
     q = jax.random.normal(ks[0], (b, t, kv, g, hd)).astype(dtype)
     k = jax.random.normal(ks[1], (b, t, kv, hd)).astype(dtype)
     v = jax.random.normal(ks[2], (b, t, kv, hd)).astype(dtype)
-    out = flash_attention_fwd(q, k, v, window=window, block_q=64, block_k=64)
+    out = flash_attention_fwd(q, k, v, window=window, block_q=64, block_k=64,
+                              interpret=True)
     oracle = ref.attention_naive(q.astype(jnp.float32),
                                  k.astype(jnp.float32),
                                  v.astype(jnp.float32), window=window)
@@ -49,7 +50,8 @@ def test_wkv6_pallas_sweep(t, chunk, dtype):
                        ).astype(jnp.float32)
     u = (jax.random.normal(ks[4], (h, kd)) * 0.3).astype(jnp.float32)
     y = wkv6_fwd(r.astype(jnp.float32), k.astype(jnp.float32),
-                 v.astype(jnp.float32), w, u, chunk=chunk)
+                 v.astype(jnp.float32), w, u, chunk=chunk,
+                 interpret=True)
     s0 = jnp.zeros((b, h, kd, vd), jnp.float32)
     oracle, _ = ref.rwkv6_naive(r.astype(jnp.float32),
                                 k.astype(jnp.float32),
@@ -70,7 +72,7 @@ def test_ssd_pallas_sweep(t, chunk):
     A = -jnp.abs(jax.random.normal(ks[2], (h,)))
     B = jax.random.normal(ks[3], (bt, t, n)) * 0.5
     C = jax.random.normal(ks[4], (bt, t, n)) * 0.5
-    y = ssd_fwd(x, dt, A, B, C, chunk=chunk)
+    y = ssd_fwd(x, dt, A, B, C, chunk=chunk, interpret=True)
     s0 = jnp.zeros((bt, h, p, n), jnp.float32)
     oracle, _ = ref.mamba2_naive(x, dt, A, B, C, s0)
     np.testing.assert_allclose(np.asarray(y), np.asarray(oracle),
@@ -81,15 +83,16 @@ def test_ssd_pallas_sweep(t, chunk):
 @pytest.mark.parametrize("n,block", [(1000, 256), (4096, 4096), (10000, 512)])
 def test_checksum_pallas_matches_ref(n, block):
     data = jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(2654435761)
-    got = checksum_pallas(data, block=block)
+    got = checksum_pallas(data, block=block, interpret=True)
     want = ref.checksum(data, block=4096)   # block must not matter
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_checksum_pallas_detects_bitflip():
     data = jnp.arange(5000, dtype=jnp.uint32)
-    c0 = checksum_pallas(data, block=1024)
-    c1 = checksum_pallas(data.at[777].set(42), block=1024)
+    c0 = checksum_pallas(data, block=1024, interpret=True)
+    c1 = checksum_pallas(data.at[777].set(42), block=1024,
+                         interpret=True)
     assert not np.array_equal(np.asarray(c0), np.asarray(c1))
 
 

@@ -19,7 +19,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 
 from repro.configs import get_arch
 from repro.models.moe import moe_block, init_moe_block
@@ -28,7 +28,8 @@ from repro.parallel import ctx, sharding as shd
 import dataclasses
 
 assert len(jax.devices()) == 8, jax.devices()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 
 # ---------- MoE: shard_map vs local (EP variant: E=4 divides model=4) ----
 cfg = dataclasses.replace(get_arch("arctic-480b").reduced(),
@@ -109,8 +110,9 @@ def test_multidevice_equivalence():
     res = subprocess.run(
         [sys.executable, "-c", _SCRIPT],
         capture_output=True, text=True, timeout=900,
+        # JAX_PLATFORMS=cpu: the child must never claim an accelerator
         env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
-             "HOME": os.environ.get("HOME", "/root")},
+             "HOME": os.path.expanduser("~"), "JAX_PLATFORMS": "cpu"},
         cwd=repo_root,
     )
     assert res.returncode == 0, f"STDOUT:\n{res.stdout}\nSTDERR:\n{res.stderr[-3000:]}"
