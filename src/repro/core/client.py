@@ -46,6 +46,7 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
+from .. import obs
 from ..analysis import knobs
 from ..analysis import sanitizer as _san
 from ..cache.extent_cache import TieredExtentCache
@@ -1548,41 +1549,44 @@ class CfsClient:
         With ``hedge_us`` set, a first attempt whose modeled cost blows the
         budget races the next replica and only the winner's cost is charged
         (the promoted ``storage/datapipe.hedged_read_file`` logic)."""
-        op = self.net.current_op
-        if op is not None and op.timed:
-            data, done, _tx = self._timed_fetch(dp, eid, eoff, size,
-                                                op.now_us, hedge_us)
-            op.advance_to(done)
+        with obs.span("client.fetch", bytes=size) as sp:
+            op = self.net.current_op
+            if op is not None and op.timed:
+                data, done, _tx = self._timed_fetch(dp, eid, eoff, size,
+                                                    op.now_us, hedge_us)
+                op.advance_to(done)
+                return data
+            gid = f"dp{dp.pid}"
+            order = self._read_order(gid, dp.replicas)
+            attempts: List[Tuple[float, int, str, bytes]] = []
+            last_err: Exception = NotFound(gid)
+            for idx, nid in enumerate(order):
+                sp.add(attempts=1)
+                self.net.begin_op()     # untimed sub-op measures the cost
+                try:
+                    d = self._serve_read_call(dp, nid, eid, eoff, size)
+                except (NetError, ExtentError, Busy) as e:
+                    last_err = e
+                    self.net.end_op()
+                    continue
+                cost = self.net.end_op().us
+                self.stats["data_calls"] += 1
+                attempts.append((cost, idx, nid, d))
+                if hedge_us is None or cost <= hedge_us or len(attempts) > 1:
+                    break
+                if idx + 1 >= len(order):
+                    break           # no replica left to race against
+                # budget blown: race the next replica; min() charges the
+                # winner
+                self.stats["hedged_reads"] += 1
+            if not attempts:
+                raise last_err
+            cost, _, nid, data = min(attempts, key=lambda a: (a[0], a[1]))
+            self.read_affinity[gid] = nid
+            self._observe_read(gid, cost)
+            if op is not None:
+                op.add(cost)
             return data
-        gid = f"dp{dp.pid}"
-        order = self._read_order(gid, dp.replicas)
-        attempts: List[Tuple[float, int, str, bytes]] = []
-        last_err: Exception = NotFound(gid)
-        for idx, nid in enumerate(order):
-            self.net.begin_op()         # untimed sub-op measures the cost
-            try:
-                d = self._serve_read_call(dp, nid, eid, eoff, size)
-            except (NetError, ExtentError, Busy) as e:
-                last_err = e
-                self.net.end_op()
-                continue
-            cost = self.net.end_op().us
-            self.stats["data_calls"] += 1
-            attempts.append((cost, idx, nid, d))
-            if hedge_us is None or cost <= hedge_us or len(attempts) > 1:
-                break
-            if idx + 1 >= len(order):
-                break               # no replica left to race against
-            # budget blown: race the next replica; min() charges the winner
-            self.stats["hedged_reads"] += 1
-        if not attempts:
-            raise last_err
-        cost, _, nid, data = min(attempts, key=lambda a: (a[0], a[1]))
-        self.read_affinity[gid] = nid
-        self._observe_read(gid, cost)
-        if op is not None:
-            op.add(cost)
-        return data
 
     def _timed_fetch(self, dp: _DataPartition, eid: int, eoff: int,
                      size: int, at: float, hedge_us: Optional[float] = None
@@ -1815,26 +1819,29 @@ class CfsFile:
 
     # ---- read ------------------------------------------------------------------
     def read(self, size: int = -1) -> bytes:
-        self.flush()
-        # read-your-writes: a read behind the window waits for the acks
-        self.client.drain_window(self._inflight)
-        if size < 0:
-            size = self._size - self.pos
-        start = self.pos
-        op = self.client.net.current_op
-        ra_on = (op is not None and op.timed and
-                 self.client.read_window > 0 and size > 0)
-        data = self._ra_serve(start, size) if ra_on else None
-        if data is None:
-            data = self.client.read_extents(self._inode_view(), start, size)
-        self.pos += len(data)
-        seq = start == self._ra_next
-        self._ra_next = start + len(data)
-        if ra_on and seq and len(data) > 0:
-            # a confirmed forward scan keeps up to read_window IO-sized
-            # chunks prefetched ahead of the reader
-            self._ra_topup(self._ra_next, len(data))
-        return data
+        with obs.span("client.read") as sp:
+            self.flush()
+            # read-your-writes: a read behind the window waits for the acks
+            self.client.drain_window(self._inflight)
+            if size < 0:
+                size = self._size - self.pos
+            start = self.pos
+            op = self.client.net.current_op
+            ra_on = (op is not None and op.timed and
+                     self.client.read_window > 0 and size > 0)
+            data = self._ra_serve(start, size) if ra_on else None
+            if data is None:
+                data = self.client.read_extents(self._inode_view(), start,
+                                                size)
+            self.pos += len(data)
+            seq = start == self._ra_next
+            self._ra_next = start + len(data)
+            if ra_on and seq and len(data) > 0:
+                # a confirmed forward scan keeps up to read_window IO-sized
+                # chunks prefetched ahead of the reader
+                self._ra_topup(self._ra_next, len(data))
+            sp.add(bytes=len(data))
+            return data
 
     def _inode_view(self) -> Dict:
         return {"inode": self.inode["inode"], "size": self._size,

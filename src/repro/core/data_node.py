@@ -26,6 +26,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
+from .. import obs
 from ..analysis import knobs
 from ..analysis import sanitizer as _san
 from .extent_store import ExtentError, ExtentStore
@@ -385,9 +386,10 @@ class DataNode:
 
     def serve_read(self, partition_id: int, extent_id: int, offset: int,
                    size: int, verify_crc: bool = False) -> bytes:
-        self._admit(self.net.model.disk_cost(size))
-        return self.partitions[partition_id].read(extent_id, offset, size,
-                                                  verify_crc=verify_crc)
+        with obs.span("datanode.read", bytes=size):
+            self._admit(self.net.model.disk_cost(size))
+            return self.partitions[partition_id].read(
+                extent_id, offset, size, verify_crc=verify_crc)
 
     def serve_append(self, partition_id: int, extent_id: int, offset: int,
                      data: bytes, create: bool = False) -> WriteResult:

@@ -34,6 +34,7 @@ import posixpath
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+from .. import obs
 from .client import (CfsClient, CfsFile, DirNotEmpty, Exists, FsError,
                      IsADirectory, NotADirectory, NotFound)
 from .meta_node import (DentryExists, MetaError, NoSuchDentry, NoSuchInode,
@@ -244,6 +245,10 @@ class CfsVfs:
     def open_file(self, path: str, flags: int = O_RDONLY) -> CfsFile:
         """The open workflow without fd bookkeeping — the compat mount uses
         this to hand out raw CfsFile handles."""
+        with obs.span("client.open"):
+            return self._open_file(path, flags)
+
+    def _open_file(self, path: str, flags: int) -> CfsFile:
         if posixpath.normpath(path) == "/":
             raise CfsOSError(errno.EISDIR, path)
         # with O_CREAT (and batching on) the up-front existence lookup is

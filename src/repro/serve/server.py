@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..configs.base import ArchConfig
 from ..models import get_model
 
@@ -47,29 +48,31 @@ class BatchServer:
         while queue:
             wave = queue[: self.batch]
             queue = queue[self.batch :]
-            # pad the wave to full batch with a dummy
-            while len(wave) < self.batch:
-                wave.append(Request(rid=-1, prompt=[0], max_new=0))
-            max_p = max(len(r.prompt) for r in wave)
-            toks = np.zeros((self.batch, max_p), np.int32)
-            for i, r in enumerate(wave):
-                toks[i, max_p - len(r.prompt):] = r.prompt  # left-pad
-            logits, cache = self._prefill(self.params, jnp.asarray(toks))
-            cur = jnp.argmax(logits[:, -1, : self.cfg.vocab], -1).astype(
-                jnp.int32)
-            outs = [[int(cur[i])] for i in range(self.batch)]
-            cache_len = jnp.int32(max_p)
-            steps = max((r.max_new for r in wave), default=0)
-            for _ in range(max(steps - 1, 0)):
-                logits, cache = self._decode(self.params, cur[:, None],
-                                             cache, cache_len)
-                cache_len = cache_len + 1
+            with obs.span("server.wave", slots=len(wave)) as sp:
+                # pad the wave to full batch with a dummy
+                while len(wave) < self.batch:
+                    wave.append(Request(rid=-1, prompt=[0], max_new=0))
+                max_p = max(len(r.prompt) for r in wave)
+                toks = np.zeros((self.batch, max_p), np.int32)
+                for i, r in enumerate(wave):
+                    toks[i, max_p - len(r.prompt):] = r.prompt  # left-pad
+                logits, cache = self._prefill(self.params, jnp.asarray(toks))
                 cur = jnp.argmax(logits[:, -1, : self.cfg.vocab], -1).astype(
                     jnp.int32)
-                for i in range(self.batch):
-                    outs[i].append(int(cur[i]))
-            for i, r in enumerate(wave):
-                if r.rid >= 0:
-                    r.out = outs[i][: r.max_new]
-                    done.append(r)
+                outs = [[int(cur[i])] for i in range(self.batch)]
+                cache_len = jnp.int32(max_p)
+                steps = max((r.max_new for r in wave), default=0)
+                for _ in range(max(steps - 1, 0)):
+                    logits, cache = self._decode(self.params, cur[:, None],
+                                                 cache, cache_len)
+                    cache_len = cache_len + 1
+                    cur = jnp.argmax(logits[:, -1, : self.cfg.vocab],
+                                     -1).astype(jnp.int32)
+                    for i in range(self.batch):
+                        outs[i].append(int(cur[i]))
+                for i, r in enumerate(wave):
+                    if r.rid >= 0:
+                        r.out = outs[i][: r.max_new]
+                        done.append(r)
+                        sp.add(tokens=len(r.out))
         return done

@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import obs
 from ..core.client import NotFound
 from ..core.fs import CfsMount
 
@@ -153,24 +154,33 @@ class CheckpointManager:
         to ``put`` (a device transfer, say) before the next one is read, so
         the host holds one leaf at a time."""
         import jax
-        step = self.latest_step() if step is None else step
-        if step is None:
-            raise NotFound("no checkpoint")
-        d = f"{self.base}/step_{step}"
-        manifest = json.loads(self.mnt.read_file(f"{d}/MANIFEST").decode())
-        flat, treedef = jax.tree_util.tree_flatten_with_path(tree_like)
-        leaves = []
-        for path, like in flat:
-            entry = manifest["tensors"][_leaf_name(path)]
-            parts = []
-            for sh in entry["shards"]:
-                data = self.mnt.read_file(sh["path"])
-                if (zlib.crc32(data) & 0xFFFFFFFF) != sh["crc32"]:
-                    raise IOError(f"checksum mismatch in {sh['path']}")
-                parts.append(bytes_to_tensor(data))
-            arr = parts[0] if len(parts) == 1 else np.concatenate(parts, 0)
-            arr = arr.reshape(entry["shape"])
-            if hasattr(like, "dtype"):
-                arr = arr.astype(like.dtype, copy=False)
-            leaves.append(put(arr))
-        return jax.tree_util.tree_unflatten(treedef, leaves), step
+        with obs.span("ckpt.restore") as sp:
+            step = self.latest_step() if step is None else step
+            if step is None:
+                raise NotFound("no checkpoint")
+            d = f"{self.base}/step_{step}"
+            manifest = json.loads(
+                self.mnt.read_file(f"{d}/MANIFEST").decode())
+            flat, treedef = jax.tree_util.tree_flatten_with_path(tree_like)
+            leaves = []
+            for path, like in flat:
+                entry = manifest["tensors"][_leaf_name(path)]
+                parts = []
+                for sh in entry["shards"]:
+                    data = self.mnt.read_file(sh["path"])
+                    sp.add(bytes=len(data))
+                    with obs.span("ckpt.crc32", bytes=len(data)):
+                        crc = zlib.crc32(data) & 0xFFFFFFFF
+                    if crc != sh["crc32"]:
+                        raise IOError(f"checksum mismatch in {sh['path']}")
+                    with obs.span("ckpt.decode", bytes=len(data)):
+                        parts.append(bytes_to_tensor(data))
+                with obs.span("ckpt.decode"):
+                    arr = (parts[0] if len(parts) == 1
+                           else np.concatenate(parts, 0))
+                    arr = arr.reshape(entry["shape"])
+                    if hasattr(like, "dtype"):
+                        arr = arr.astype(like.dtype, copy=False)
+                with obs.span("ckpt.put", bytes=arr.nbytes):
+                    leaves.append(put(arr))
+            return jax.tree_util.tree_unflatten(treedef, leaves), step

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_arch
 from repro.core import CfsCluster
 from repro.storage.checkpoint import CheckpointManager
@@ -151,8 +152,13 @@ def test_serving_batch_slots(cluster):
     srv = BatchServer(cfg, params, batch=2, smax=64)
     reqs = [Request(rid=i, prompt=[1 + i, 2 + i, 3 + i], max_new=4)
             for i in range(5)]
-    done = srv.serve(reqs)
+    with obs.recording() as rec:
+        done = srv.serve(reqs)
     assert len(done) == 5
     for r in done:
         assert len(r.out) == 4
         assert all(0 <= t < cfg.vocab for t in r.out)
+    # one span a wave, counting its requests and the tokens they got
+    waves = [s.counts for s in rec.spans if s.name == "server.wave"]
+    assert waves == [{"slots": 2, "tokens": 8}, {"slots": 2, "tokens": 8},
+                     {"slots": 1, "tokens": 4}]
