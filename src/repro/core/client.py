@@ -40,6 +40,7 @@ invalidated on seek/write/truncate, drained at the fsync/close barriers.
 from __future__ import annotations
 
 import bisect
+import io
 import math
 import random
 import zlib
@@ -1358,7 +1359,7 @@ class CfsClient:
         size = min(size, inode["size"] - offset)
         if size <= 0:
             return b""
-        out = bytearray(size)
+        out = io.BytesIO()
         pieces = self._map_pieces(inode, offset, size)
         op = self.net.current_op
         if op is not None and op.timed and self.read_window > 0:
@@ -1368,9 +1369,10 @@ class CfsClient:
         else:
             for (pos, pid, eid, eoff, ln) in pieces:
                 dp = self._dp(pid)
-                chunk = self._read_one(dp, eid, eoff, ln, hedge_us=hedge_us)
-                out[pos : pos + len(chunk)] = chunk
-        return bytes(out)
+                out.seek(pos)
+                out.write(self._read_one(dp, eid, eoff, ln,
+                                         hedge_us=hedge_us))
+        return _assembled(out, size)
 
     def read_extents_at(self, inode: Dict, offset: int, size: int,
                         at: float, hedge_us: Optional[float] = None
@@ -1383,11 +1385,11 @@ class CfsClient:
         size = min(size, inode["size"] - offset)
         if size <= 0:
             return b"", at
-        out = bytearray(size)
+        out = io.BytesIO()
         done = self._windowed_fetch(out, self._map_pieces(inode, offset, size),
                                     at, hedge_us,
                                     cache_ctx=self._cache_ctx(inode))
-        return bytes(out), done
+        return _assembled(out, size), done
 
     def _cache_ctx(self, inode: Dict
                    ) -> Optional[Tuple[int, int, Optional[float], float]]:
@@ -1444,7 +1446,7 @@ class CfsClient:
                            hi - lo))
         return pieces
 
-    def _windowed_fetch(self, out: bytearray,
+    def _windowed_fetch(self, out: io.BytesIO,
                         pieces: List[Tuple[int, int, int, int, int]],
                         at: float, hedge_us: Optional[float] = None,
                         cache_ctx: Optional[
@@ -1480,7 +1482,8 @@ class CfsClient:
                     hit = cache.serve(key, n, cache_ctx, send_frontier)
                     if hit is not None:
                         data, done = hit
-                        out[pos + off : pos + off + n] = data
+                        out.seek(pos + off)
+                        out.write(data)
                         send_frontier = max(send_frontier, done)
                         last_done = max(last_done, done)
                         self.stats["data_cache_hits"] += 1
@@ -1494,7 +1497,8 @@ class CfsClient:
                     send_at = max(send_at, first)
                 data, done, tx_done = self._timed_fetch(
                     dp, eid, eoff + off, n, send_at, hedge_us)
-                out[pos + off : pos + off + len(data)] = data
+                out.seek(pos + off)
+                out.write(data)
                 if cache is not None and len(data) == n:
                     cache.insert((self.volume, pid, eid, eoff + off),
                                  bytes(data), cache_ctx, done)
@@ -1681,6 +1685,19 @@ class CfsClient:
     def _observe_read(self, gid: str, lat_us: float) -> None:
         self._read_lat.setdefault(gid, _LatencyEwma()).observe(lat_us)
         self._read_lat_all.observe(lat_us)
+
+
+def _assembled(out: io.BytesIO, size: int) -> bytes:
+    """The ``size`` bytes of a read whose pieces were written into ``out``
+    at their positions; zeros where none landed (``io.BytesIO`` zero-fills
+    what a write seeks past, and the tail is filled here).  Pieces written
+    front to back, as a file's extents map them, only grow the buffer, and
+    ``getvalue`` hands it over without a copy: each byte of the read is
+    written once, with no zero-fill before it and no copy after."""
+    if out.seek(0, io.SEEK_END) < size:
+        out.seek(size - 1)
+        out.write(b"\0")
+    return out.getvalue()
 
 
 def _uncovered(lo: int, hi: int,

@@ -13,14 +13,15 @@ crashes).  Every tensor carries a CRC32 in the manifest, verified on load
 (the device-side Pallas ``checksum`` kernel plays this role on TPU).
 
 Elasticity: tensors are split into ``shards`` along dim 0 where possible —
-restore concatenates, so a checkpoint written by H hosts loads on H' ≠ H
-(re-sharding happens at device_put with the new mesh's shardings).
+restore copies each shard into its rows of the leaf, so a
+checkpoint written by H hosts loads on H' ≠ H (re-sharding happens at
+device_put with the new mesh's shardings).
 """
 
 from __future__ import annotations
 
-import io
 import json
+import math
 import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -51,11 +52,33 @@ def tensor_to_bytes(arr: np.ndarray) -> bytes:
 
 
 def bytes_to_tensor(data: bytes) -> np.ndarray:
-    assert data[:4] == _MAGIC, "bad tensor file"
+    dtype, shape, offset = _parse_header(data)
+    return np.frombuffer(data, dtype=dtype,
+                         offset=offset).reshape(shape).copy()
+
+
+def _parse_header(data: bytes) -> Tuple[np.dtype, Tuple[int, ...], int]:
+    """The dtype and shape of a tensor file, and the offset of its rows."""
+    if data[:4] != _MAGIC:
+        raise IOError("bad tensor file")
     hlen = int.from_bytes(data[4:8], "little")
     header = json.loads(data[8 : 8 + hlen].decode())
-    return np.frombuffer(data, dtype=np.dtype(header["dtype"]),
-                         offset=8 + hlen).reshape(header["shape"]).copy()
+    return np.dtype(header["dtype"]), tuple(header["shape"]), 8 + hlen
+
+
+def _decode_into(data: bytes, leaf: np.ndarray, rows: np.ndarray,
+                 path: str) -> int:
+    """Copy the rows of the tensor file ``data`` to the front of ``rows``,
+    the bytes of ``leaf`` from the file's first row on; return how many.
+    The file must hold the leaf's dtype and trailing shape, and fit."""
+    dtype, shape, offset = _parse_header(data)
+    n = len(data) - offset
+    if (dtype != leaf.dtype or len(shape) != leaf.ndim
+            or shape[1:] != leaf.shape[1:] or n > rows.nbytes
+            or n != math.prod(shape) * dtype.itemsize):
+        raise IOError(f"{path} does not fit its leaf")
+    rows[:n] = np.frombuffer(data, np.uint8, n, offset)
+    return n
 
 
 def _leaf_name(path) -> str:
@@ -164,8 +187,12 @@ class CheckpointManager:
             flat, treedef = jax.tree_util.tree_flatten_with_path(tree_like)
             leaves = []
             for path, like in flat:
-                entry = manifest["tensors"][_leaf_name(path)]
-                parts = []
+                name = _leaf_name(path)
+                entry = manifest["tensors"][name]
+                # a buffer of its own for every leaf: ``put`` may keep it
+                arr = np.empty(entry["shape"], np.dtype(entry["dtype"]))
+                rows = arr.reshape(-1).view(np.uint8)
+                at = 0
                 for sh in entry["shards"]:
                     data = self.mnt.read_file(sh["path"])
                     sp.add(bytes=len(data))
@@ -174,12 +201,12 @@ class CheckpointManager:
                     if crc != sh["crc32"]:
                         raise IOError(f"checksum mismatch in {sh['path']}")
                     with obs.span("ckpt.decode", bytes=len(data)):
-                        parts.append(bytes_to_tensor(data))
-                with obs.span("ckpt.decode"):
-                    arr = (parts[0] if len(parts) == 1
-                           else np.concatenate(parts, 0))
-                    arr = arr.reshape(entry["shape"])
-                    if hasattr(like, "dtype"):
+                        at += _decode_into(data, arr, rows[at:], sh["path"])
+                if at != rows.nbytes:
+                    raise IOError(f"the shards of {name} hold {at} of its "
+                                  f"{rows.nbytes} bytes")
+                if hasattr(like, "dtype"):
+                    with obs.span("ckpt.decode"):
                         arr = arr.astype(like.dtype, copy=False)
                 with obs.span("ckpt.put", bytes=arr.nbytes):
                     leaves.append(put(arr))

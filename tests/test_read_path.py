@@ -376,6 +376,80 @@ def test_terminal_notleader_surfaces_as_fserror():
         cl._data_call(fake, "serve_append", 777, 0, b"z", True, nbytes=128)
 
 
+# ------------------------------------------------------------ read assembly
+_AS_SIZE = 5 * PACKET_SIZE + 4096
+_AS_DATA = bytes(range(251)) * (3 * PACKET_SIZE // 251 + 1)
+_AS_FILE = (_AS_DATA[:3 * PACKET_SIZE] + bytes(2 * PACKET_SIZE)
+            + b"tail" * 1024)
+_AS_CASES = {   # case: (offset, size, timed op with a read window)
+    "inside_an_extent": (1000, 5000, False),
+    "across_extents": (PACKET_SIZE - 1000, 2 * PACKET_SIZE, False),
+    "hole_reads_zeros": (3 * PACKET_SIZE - 10, 2 * PACKET_SIZE + 20, False),
+    "trailing_hole": (3 * PACKET_SIZE - 10, 2 * PACKET_SIZE + 20, False),
+    "short_at_eof": (_AS_SIZE - 300, 4096, False),
+    "timed_window": (0, PACKET_SIZE, True),
+}
+
+
+def _as_mount(extents=None):
+    """A fresh cluster holding /as.bin: three packets of data, a hole from
+    ftruncate-grow, then a tail (or, for ``trailing_hole``, no tail); and a
+    new client to read it."""
+    c = _cluster()
+    vfs = c.mount("v", client_id="w").vfs
+    fd = vfs.open("/as.bin", O_RDWR | O_CREAT)
+    vfs.pwrite(fd, _AS_DATA, 0)
+    vfs.ftruncate(fd, 3 * PACKET_SIZE)
+    vfs.ftruncate(fd, 5 * PACKET_SIZE)
+    if extents != "trailing_hole":
+        vfs.pwrite(fd, b"tail" * 1024, 5 * PACKET_SIZE)
+    vfs.close(fd)
+    mnt = c.mount("v", client_id="r")
+    mnt.client.read_window = 8
+    mnt.client.hedge_reads = False
+    return c, mnt
+
+
+@pytest.mark.parametrize("case", list(_AS_CASES))
+def test_read_assembles_the_file_bytes(case):
+    """A read lays its pieces down by file offset: the bytes the file
+    holds, holes (inside it and at its end) as zeros, a short read at EOF,
+    the same bytes whatever order the extent map lists its extents in, and
+    under a timed op with a read window (readahead hits included) the
+    bytes of the untimed read.  What it returns is ``bytes``."""
+    offset, size, timed = _AS_CASES[case]
+    c, mnt = _as_mount(case)
+    whole = (_AS_FILE if case != "trailing_hole"
+             else _AS_FILE[:5 * PACKET_SIZE])
+    want = whole[offset:offset + size]
+    f = mnt.open("/as.bin", "r")
+    f.seek(offset)
+    if timed:
+        c.net.begin_op(at=0.0)
+        try:
+            got = b"".join(iter(lambda: f.read(size), b""))
+        finally:
+            c.net.end_op()
+        want = whole
+        assert mnt.client.stats["ra_hits"] > 0
+    else:
+        got = f.read(size)
+    assert type(got) is bytes and got == want
+    if case == "short_at_eof":
+        assert len(got) == 300
+    if case in ("hole_reads_zeros", "trailing_hole"):
+        assert got[10:10 + 2 * PACKET_SIZE] == bytes(2 * PACKET_SIZE)
+    inode = mnt.stat("/as.bin")
+    if case == "across_extents":
+        assert any(offset < foff < offset + size
+                   for _, _, foff, _, _ in inode["extents"])
+    # the extent map in reverse order reads the same bytes
+    backwards = dict(inode, extents=inode["extents"][::-1])
+    assert len(inode["extents"]) > 1
+    assert mnt.client.read_extents(backwards, offset, size) == \
+        whole[offset:offset + size]
+
+
 # ---------------------------------------------------- sparse hedged_read_file
 def test_hedged_read_file_handles_sparse_files():
     """Regression: the old reassembly concatenated extents in map order,

@@ -2,6 +2,7 @@
 deterministic replay, crash safety, hedged reads, elastic restore."""
 
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,7 @@ import pytest
 from repro import obs
 from repro.configs import get_arch
 from repro.core import CfsCluster
-from repro.storage.checkpoint import CheckpointManager
+from repro.storage.checkpoint import CheckpointManager, bytes_to_tensor
 from repro.storage.datapipe import ShardReader, ShardWriter, hedged_read_file
 from repro.train import optimizer as opt
 from repro.train.trainer import Trainer, TrainerConfig
@@ -97,6 +98,103 @@ def test_checkpoint_detects_corruption(cluster, data_volume):
     f.close()
     with pytest.raises(IOError):
         cm.restore({"w": np.zeros((8, 8), np.float32)})
+
+
+def _leaves(dtype, seed=0):
+    """Leaves of every shard layout: a matrix and a vector whose rows
+    divide by 2 (two shards with ``shards=2``), a matrix whose rows do not
+    and a scalar (one shard)."""
+    rng = np.random.RandomState(seed)
+    return {"a": rng.randn(6, 5).astype(dtype),
+            "b": rng.randn(7, 3).astype(dtype),
+            "c": rng.randn(10).astype(dtype),
+            "d": np.asarray(rng.randn(), dtype)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shards", [1, 2])
+def test_restore_matches_decoding_each_shard(cluster, shards, dtype):
+    """Each leaf, its shards decoded straight into its host array, is bit
+    for bit what ``bytes_to_tensor`` of each shard, concatenated, gives."""
+    dt = np.dtype(jnp.bfloat16) if dtype == "bfloat16" else np.dtype(dtype)
+    mnt = cluster.mount("train")
+    base = f"/ck_rd_{shards}_{dtype}"
+    tree = _leaves(dt)
+    CheckpointManager(mnt, base, shards=shards).save(2, tree)
+    manifest = json.loads(mnt.read_file(f"{base}/step_2/MANIFEST").decode())
+    got, step = CheckpointManager(cluster.mount("train"), base).restore(
+        {k: np.zeros(v.shape, dt) for k, v in tree.items()})
+    assert step == 2
+    for k, v in tree.items():
+        entry = manifest["tensors"][k]
+        parts = [bytes_to_tensor(mnt.read_file(sh["path"]))
+                 for sh in entry["shards"]]
+        want = np.concatenate(parts, 0) if len(parts) > 1 else parts[0]
+        want = want.reshape(entry["shape"])
+        assert len(entry["shards"]) == (2 if shards == 2 and k in "ac"
+                                        else 1)
+        assert got[k].dtype == want.dtype == dt
+        assert got[k].shape == want.shape == v.shape
+        assert got[k].tobytes() == want.tobytes() == v.tobytes()
+
+
+def test_restore_casts_to_the_dtype_asked_for(cluster):
+    mnt = cluster.mount("train")
+    tree = _leaves(np.float32, seed=1)
+    CheckpointManager(mnt, "/ck_cast", shards=2).save(1, tree)
+    got, _ = CheckpointManager(mnt, "/ck_cast", shards=2).restore(
+        jax.eval_shape(lambda: jax.tree.map(
+            lambda x: jnp.asarray(x, jnp.bfloat16), tree)))
+    for k, v in tree.items():
+        assert got[k].dtype == jnp.bfloat16
+        assert got[k].tobytes() == v.astype(jnp.bfloat16).tobytes()
+
+
+@pytest.mark.parametrize("where", ["magic", "header_length", "header_json",
+                                   "raw", "trailing_bytes"])
+def test_restore_refuses_a_flipped_bit_before_put(cluster, where):
+    """A bit flipped in a shard's header or in its rows, or bytes after its
+    rows, raise IOError, and the leaf is never handed to ``put``; the
+    leaves before it were."""
+    mnt = cluster.mount("train")
+    base = f"/ck_flip_{where}"
+    tree = _leaves(np.float32, seed=2)
+    CheckpointManager(mnt, base, shards=2).save(1, tree)
+    path = f"{base}/step_1/b.shard0"
+    size = mnt.stat(path)["size"]
+    f = mnt.open(path, "r+")
+    if where == "trailing_bytes":
+        f.seek(size)
+        f.write(b"\0" * 4)
+    else:
+        at = {"magic": 1, "header_length": 4, "header_json": 12,
+              "raw": size - 3}[where]
+        f.seek(at)
+        byte = f.read(1)[0]
+        f.seek(at)
+        f.write(bytes([byte ^ 0x10]))
+    f.close()
+    put = []
+    with pytest.raises(IOError):
+        CheckpointManager(cluster.mount("train"), base).restore(
+            {k: np.zeros(v.shape, v.dtype) for k, v in tree.items()},
+            put=lambda arr: put.append(arr) or arr)
+    assert len(put) == 1 and put[0].tobytes() == tree["a"].tobytes()
+
+
+def test_restored_leaves_are_writable_and_their_own(cluster):
+    mnt = cluster.mount("train")
+    tree = _leaves(np.float32, seed=3)
+    CheckpointManager(mnt, "/ck_own", shards=2).save(1, tree)
+    got, _ = CheckpointManager(mnt, "/ck_own", shards=2).restore(
+        {k: np.zeros(v.shape, v.dtype) for k, v in tree.items()})
+    for k, x in got.items():
+        assert x.flags.writeable
+        assert not any(np.shares_memory(x, y) for j, y in got.items()
+                       if j != k)
+    got["a"][...] = 0
+    for k in "bcd":
+        assert got[k].tobytes() == tree[k].tobytes()
 
 
 def test_elastic_restore_different_shard_count(cluster, data_volume):
