@@ -8,6 +8,7 @@ from .chameleon_34b import CONFIG as _chameleon
 from .codeqwen15_7b import CONFIG as _codeqwen
 from .minicpm_2b import CONFIG as _minicpm
 from .mixtral_8x22b import CONFIG as _mixtral
+from .moonlight_16b_a3b import CONFIG as _moonlight
 from .musicgen_large import CONFIG as _musicgen
 from .phi3_medium_14b import CONFIG as _phi3
 from .qwen15_32b import CONFIG as _qwen32
@@ -18,7 +19,7 @@ ARCHS: Dict[str, ArchConfig] = {
     c.name: c
     for c in [
         _codeqwen, _phi3, _minicpm, _qwen32, _rwkv6,
-        _arctic, _mixtral, _zamba2, _musicgen, _chameleon,
+        _arctic, _mixtral, _zamba2, _musicgen, _chameleon, _moonlight,
     ]
 }
 

@@ -316,8 +316,8 @@ def embed(p: Params, tokens: jnp.ndarray) -> jnp.ndarray:
     return jnp.take(p["tok"], tokens, axis=0)
 
 
-def unembed(p: Params, x: jnp.ndarray) -> jnp.ndarray:
-    x = rms_norm(x, p["ln_f"])
+def unembed(p: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    x = rms_norm(x, p["ln_f"], eps)
     if "out" in p:
         return jnp.einsum("btd,dv->btv", x, p["out"])
     return jnp.einsum("btd,vd->btv", x, p["tok"])

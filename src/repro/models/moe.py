@@ -15,6 +15,11 @@ Expert-parallel sharding puts E over the "model" mesh axis when divisible
 (arctic: 128/16 = 8 experts per shard); otherwise the expert hidden dim is
 tensor-parallel instead (mixtral: 8e replicated, f=16384 sharded 16-way).
 XLA inserts the token all-to-all at the scatter/gather boundaries.
+
+The expert-share layer below (``moe_share``, moonlight-16b-a3b) is the
+other path: one chip's share of the experts, no capacity, nothing
+dropped.  Mixtral and arctic keep the capacity path above; the two share
+no routing code.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..configs.base import ArchConfig
-from .layers import Params, _dense_init
+from .layers import Params, _dense_init, init_mlp, swiglu
 
 
 def init_moe_block(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
@@ -216,7 +221,6 @@ def moe_block(cfg: ArchConfig, p: Params, x: jnp.ndarray,
     w = top_w.reshape(G, ng * k, 1).astype(x.dtype)
     out = jnp.sum((gathered * w).reshape(G, ng, k, d), axis=2).reshape(b, t, d)
     if mlp is not None:
-        from .layers import swiglu
         out = out + swiglu(mlp, x)
     return out
 
@@ -228,3 +232,101 @@ def load_balance_loss(cfg: ArchConfig, gate_probs: jnp.ndarray,
     me = jnp.mean(jax.nn.one_hot(top_e[..., 0], E), axis=0)
     pe = jnp.mean(gate_probs, axis=0)
     return E * jnp.sum(me * pe)
+
+
+# ------------------------------------------------------------ expert share
+#
+# One chip's share of an expert-parallel layer (DeepSeek-V3 style): the
+# router keeps its published width and experts per token, the chip holds
+# experts [expert_lo, expert_lo + n_experts_held) and computes their part
+# of the result for every token routed to them, without a capacity: none
+# is dropped.  What the absent experts would add is left out, as it lies
+# on other chips; the shared experts are always added.  No exchange runs.
+
+
+def init_moe_share(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
+    d, E, held = cfg.d_model, cfg.n_experts, cfg.n_experts_held
+    fe = cfg.d_expert or cfg.d_ff
+    ks = jax.random.split(key, 5)
+    scale = (2.0 / (d + fe)) ** 0.5
+
+    def experts(k, shape):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+    p = {"router": _dense_init(ks[0], d, E, dtype),
+         "w1": experts(ks[1], (held, d, fe)),
+         "w3": experts(ks[2], (held, d, fe)),
+         "w2": experts(ks[3], (held, fe, d))}
+    if cfg.router == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), dtype)
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(d, cfg.n_shared_experts * fe, ks[4], dtype)
+    return p
+
+
+def route_topk(cfg: ArchConfig, p: Params, x: jnp.ndarray):
+    """x [N, d] -> (experts [N, k] int32, weights [N, k] float32), over all
+    ``n_experts``.  Scores are float32 throughout: softmax, or sigmoid
+    with top-k taken of score + correction bias (the weights are the
+    scores without it); the chosen weights are normalised and scaled by
+    ``routed_scale``."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores + p["router_bias"].astype(jnp.float32)
+    else:
+        scores = choice = jax.nn.softmax(logits, axis=-1)
+    _, top_e = lax.top_k(choice, cfg.top_k)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, -1, keepdims=True) + 1e-20)
+    return top_e, top_w * cfg.routed_scale
+
+
+def held_experts(cfg: ArchConfig, p: Params, x: jnp.ndarray,
+                 top_e: jnp.ndarray, top_w: jnp.ndarray):
+    """The held experts' part of the routed result, x [N, d] -> [N, d],
+    and (rows computed, the busiest held expert's rows) as int32 [2].
+
+    The N*k assignments are sorted by held expert, those to absent experts
+    last; one ragged product per weight runs over a buffer of N*min(k,
+    held) rows, the most the held experts can be given, so none is
+    dropped; rows past the held groups are not read."""
+    n, k = top_e.shape
+    held, lo = cfg.n_experts_held, cfg.expert_lo
+    local = top_e.reshape(-1) - lo
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held)
+    order = jnp.argsort(group, stable=True)
+    rows = n * min(k, held)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+    xs = jnp.take(x, order[:rows] // k, axis=0)
+    h = (jax.nn.silu(lax.ragged_dot(xs, p["w1"], sizes))
+         * lax.ragged_dot(xs, p["w3"], sizes))
+    ys = lax.ragged_dot(h, p["w2"], sizes)
+    # back to assignment order; an absent expert's assignment reads zero,
+    # never a row past the held groups
+    where = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    back = jnp.take(ys, jnp.where(mine, where, rows), axis=0, mode="fill",
+                    fill_value=0)
+    w = jnp.where(mine, top_w.reshape(-1), 0.0).reshape(n, k)
+    out = jnp.einsum("nkd,nk->nd", back.reshape(n, k, -1), w,
+                     preferred_element_type=jnp.float32)
+    counts = jnp.stack([jnp.sum(sizes), jnp.max(sizes)])
+    return out.astype(x.dtype), counts
+
+
+def moe_share(cfg: ArchConfig, p: Params, x: jnp.ndarray):
+    """x [B, T, d] (normed) -> (held experts' part + shared experts
+    [B, T, d], int32 [2] rows computed and busiest held expert's rows)."""
+    b, t, d = x.shape
+    xf = x.reshape(b * t, d)
+    with jax.named_scope("moe.route"):
+        top_e, top_w = route_topk(cfg, p, xf)
+    with jax.named_scope("moe.experts"):
+        out, counts = held_experts(cfg, p, xf, top_e, top_w)
+    out = out.reshape(b, t, d)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            out = out + swiglu(p["shared"], x)
+    return out, counts

@@ -57,6 +57,7 @@ class BatchServer:
                 for i, r in enumerate(wave):
                     toks[i, max_p - len(r.prompt):] = r.prompt  # left-pad
                 logits, cache = self._prefill(self.params, jnp.asarray(toks))
+                busiest = _count_experts(sp, cache, 0)
                 cur = jnp.argmax(logits[:, -1, : self.cfg.vocab], -1).astype(
                     jnp.int32)
                 outs = [[int(cur[i])] for i in range(self.batch)]
@@ -66,13 +67,28 @@ class BatchServer:
                     logits, cache = self._decode(self.params, cur[:, None],
                                                  cache, cache_len)
                     cache_len = cache_len + 1
+                    busiest = _count_experts(sp, cache, busiest)
                     cur = jnp.argmax(logits[:, -1, : self.cfg.vocab],
                                      -1).astype(jnp.int32)
                     for i in range(self.batch):
                         outs[i].append(int(cur[i]))
+                if busiest is not None:
+                    sp.add(expert_rows_max=busiest)
                 for i, r in enumerate(wave):
                     if r.rid >= 0:
                         r.out = outs[i][: r.max_new]
                         done.append(r)
                         sp.add(tokens=len(r.out))
         return done
+
+
+def _count_experts(sp, cache, busiest: Optional[int]) -> Optional[int]:
+    """Adds the expert-share layer's rows of one prefill or decode step
+    (``cache["expert_rows"]``: rows computed, busiest expert's rows) to the
+    wave's span; returns the busiest expert's rows so far, or None for a
+    model without that layer."""
+    if "expert_rows" not in cache:
+        return None
+    rows, most = (int(v) for v in np.asarray(cache["expert_rows"]))
+    sp.add(expert_rows=rows)
+    return max(busiest or 0, most)

@@ -118,3 +118,20 @@ def test_swa_restricts_context():
     logits2, _ = api.prefill(params, toks2, t, remat=False)
     np.testing.assert_allclose(np.asarray(logits1), np.asarray(logits2),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_minicpm_prefill_logits_are_pinned():
+    """minicpm-2b's reduced prefill, bf16 weights from a fixed key: its
+    logits bit for bit as recorded before latent attention and the
+    expert-share layer joined the backbone (tests/fixtures)."""
+    from pathlib import Path
+    cfg = get_arch("minicpm-2b").reduced()
+    api = get_model(cfg)
+    params = api.init(jax.random.PRNGKey(2024), jnp.bfloat16)
+    toks = jax.random.randint(jax.random.PRNGKey(7), (2, 32), 0, cfg.vocab,
+                              jnp.int32)
+    logits, _ = jax.jit(lambda p, t: api.prefill(p, t, 40, "bfloat16",
+                                                 False))(params, toks)
+    pinned = np.load(Path(__file__).parent / "fixtures"
+                     / "minicpm_prefill_logits.npy")
+    np.testing.assert_array_equal(np.asarray(logits, np.float32), pinned)

@@ -1,8 +1,9 @@
 """Compiles for a described TPU v5e chip, with no chip attached.
 
-The four Pallas kernels at the widths of the layers they serve, and the
+The four Pallas kernels at the widths of the layers they serve, the
 train step ``chip_smoke.py`` runs (minicpm-2b at published widths, depth
-cut), whose memory must fit one chip.  What the chip's compiler refuses
+cut) and the benchmark's Moonlight serving prefill, whose memory must fit
+one chip.  What the chip's compiler refuses
 here costs no chip time.  Nothing runs, so nothing here is a timing.
 
 The topology is described only inside the module fixture: libtpu may be
@@ -117,3 +118,26 @@ def test_smoke_train_step_fits_one_v5e(one_chip, smoke):
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert peak < V5E_HBM_BYTES, f"train step needs {peak} bytes"
+
+
+def test_moonlight_prefill_fits_one_v5e(one_chip):
+    """The serving prefill of the benchmarked Moonlight share (21 layers,
+    8 of 64 experts held, bf16) for a wave of 32 prompts of 1,024 tokens,
+    as ``BatchServer`` jits it: the weights, the latent cache and the
+    prefill's temporaries fit one chip's 16 GiB."""
+    import dataclasses
+    from repro.serve.server import BatchServer
+    cfg = dataclasses.replace(get_arch("moonlight-16b-a3b"), n_layers=21,
+                              n_experts_held=8)
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: get_model(cfg).init(jax.random.PRNGKey(0), jnp.bfloat16)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 2 * 2_762_180_352
+    srv = BatchServer(cfg, None, batch=32, smax=1025)
+    tokens = on_chip(jax.ShapeDtypeStruct((32, 1024), jnp.int32))
+    mem = srv._prefill.lower(params, tokens).compile().memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert peak < V5E_HBM_BYTES, f"prefill needs {peak} bytes"
