@@ -12,10 +12,17 @@ any point leaves the previous checkpoint loadable (tested with injected
 crashes).  Every tensor carries a CRC32 in the manifest, verified on load
 (the device-side Pallas ``checksum`` kernel plays this role on TPU).
 
-Elasticity: tensors are split into ``shards`` along dim 0 where possible —
-restore copies each shard into its rows of the leaf, so a
+Elasticity: tensors are split into ``shards`` along dim 0 where possible,
+and a leaf is whatever its shards hold, joined along dim 0, so a
 checkpoint written by H hosts loads on H' ≠ H (re-sharding happens at
 device_put with the new mesh's shardings).
+
+A shard file is ``RPT1``, the header's length, a JSON header padded with
+spaces so that the rows start at a multiple of 64 bytes, then the rows.
+Restore takes the rows as a view of the bytes read: a leaf described as a
+device array goes to ``put`` shard by shard and is joined on the device,
+with no host copy of its rows; a leaf described by a host array is
+assembled in a host array of its own.
 """
 
 from __future__ import annotations
@@ -35,6 +42,7 @@ __all__ = ["CheckpointManager", "InjectedCrash", "tensor_to_bytes",
            "bytes_to_tensor"]
 
 _MAGIC = b"RPT1"
+_ROW_ALIGN = 64   # a shard's rows start at a multiple of this
 
 
 class InjectedCrash(RuntimeError):
@@ -47,6 +55,7 @@ class InjectedCrash(RuntimeError):
 def tensor_to_bytes(arr: np.ndarray) -> bytes:
     header = json.dumps({"dtype": str(arr.dtype),
                          "shape": list(arr.shape)}).encode()
+    header += b" " * (-(8 + len(header)) % _ROW_ALIGN)
     raw = np.ascontiguousarray(arr).tobytes()
     return (_MAGIC + len(header).to_bytes(4, "little") + header + raw)
 
@@ -66,19 +75,25 @@ def _parse_header(data: bytes) -> Tuple[np.dtype, Tuple[int, ...], int]:
     return np.dtype(header["dtype"]), tuple(header["shape"]), 8 + hlen
 
 
-def _decode_into(data: bytes, leaf: np.ndarray, rows: np.ndarray,
-                 path: str) -> int:
-    """Copy the rows of the tensor file ``data`` to the front of ``rows``,
-    the bytes of ``leaf`` from the file's first row on; return how many.
-    The file must hold the leaf's dtype and trailing shape, and fit."""
-    dtype, shape, offset = _parse_header(data)
-    n = len(data) - offset
-    if (dtype != leaf.dtype or len(shape) != leaf.ndim
-            or shape[1:] != leaf.shape[1:] or n > rows.nbytes
-            or n != math.prod(shape) * dtype.itemsize):
+def _rows(data: bytes, dtype: np.dtype, shape: Tuple[int, ...],
+          path: str) -> np.ndarray:
+    """The rows of the tensor file ``data``: a read-only view of its bytes.
+    The file must hold ``dtype``, the rank and trailing shape of ``shape``,
+    and exactly its rows' bytes."""
+    fdtype, fshape, offset = _parse_header(data)
+    count = math.prod(fshape)
+    if (fdtype != dtype or len(fshape) != len(shape)
+            or fshape[1:] != shape[1:]
+            or len(data) - offset != count * fdtype.itemsize):
         raise IOError(f"{path} does not fit its leaf")
-    rows[:n] = np.frombuffer(data, np.uint8, n, offset)
-    return n
+    return np.frombuffer(data, fdtype, count, offset).reshape(fshape)
+
+
+def _joined_shape(parts: List[np.ndarray]) -> Tuple[int, ...]:
+    """The shape of ``parts`` joined along dim 0 (a lone part's own)."""
+    if len(parts) == 1:
+        return parts[0].shape
+    return (sum(p.shape[0] for p in parts),) + parts[0].shape[1:]
 
 
 def _leaf_name(path) -> str:
@@ -173,9 +188,20 @@ class CheckpointManager:
                 put: Callable[[np.ndarray], Any] = lambda arr: arr
                 ) -> Tuple[Any, int]:
         """Restore into the structure and dtypes of ``tree_like`` (arrays or
-        ``ShapeDtypeStruct``s).  Each leaf is read, CRC-checked and handed
-        to ``put`` (a device transfer, say) before the next one is read, so
-        the host holds one leaf at a time."""
+        ``ShapeDtypeStruct``s).  Each leaf's shards are read, CRC-checked
+        and their headers checked before any of them is handed to ``put``
+        (a device transfer, say), and the leaf is done before the next one
+        is read, so the host holds one leaf's shard bytes at a time.
+
+        A leaf described by a device array (``jax.ShapeDtypeStruct`` or
+        ``jax.Array``) goes to ``put`` shard by shard, each shard's rows a
+        view of the bytes read, and the results are joined along dim 0 in
+        their own library (on the device for ``jax.Array``s).  A leaf
+        described otherwise is assembled in a host array of its own, handed
+        to ``put`` whole.  The ``ckpt.restore`` span counts in
+        ``host_copy_bytes`` the stored bytes of rows that went through a
+        host copy: a host-described leaf, a view not aligned to its dtype,
+        a cast, or a host ``put`` result joined or copied off the bytes."""
         import jax
         with obs.span("ckpt.restore") as sp:
             step = self.latest_step() if step is None else step
@@ -185,29 +211,80 @@ class CheckpointManager:
             manifest = json.loads(
                 self.mnt.read_file(f"{d}/MANIFEST").decode())
             flat, treedef = jax.tree_util.tree_flatten_with_path(tree_like)
+            sp.add(host_copy_bytes=0)
             leaves = []
             for path, like in flat:
                 name = _leaf_name(path)
                 entry = manifest["tensors"][name]
-                # a buffer of its own for every leaf: ``put`` may keep it
-                arr = np.empty(entry["shape"], np.dtype(entry["dtype"]))
-                rows = arr.reshape(-1).view(np.uint8)
-                at = 0
-                for sh in entry["shards"]:
-                    data = self.mnt.read_file(sh["path"])
-                    sp.add(bytes=len(data))
-                    with obs.span("ckpt.crc32", bytes=len(data)):
-                        crc = zlib.crc32(data) & 0xFFFFFFFF
-                    if crc != sh["crc32"]:
-                        raise IOError(f"checksum mismatch in {sh['path']}")
-                    with obs.span("ckpt.decode", bytes=len(data)):
-                        at += _decode_into(data, arr, rows[at:], sh["path"])
-                if at != rows.nbytes:
-                    raise IOError(f"the shards of {name} hold {at} of its "
-                                  f"{rows.nbytes} bytes")
-                if hasattr(like, "dtype"):
-                    with obs.span("ckpt.decode"):
-                        arr = arr.astype(like.dtype, copy=False)
-                with obs.span("ckpt.put", bytes=arr.nbytes):
-                    leaves.append(put(arr))
+                dtype, shape = np.dtype(entry["dtype"]), tuple(entry["shape"])
+                want = np.dtype(like.dtype) if hasattr(like, "dtype") else dtype
+                views = self._read_rows(entry, dtype, shape, name, sp)
+                if isinstance(like, (jax.ShapeDtypeStruct, jax.Array)):
+                    leaf, copied = _put_by_shard(views, want, put)
+                else:
+                    leaf, copied = _put_whole(views, want, put)
+                del views  # this leaf's shard bytes, before the next leaf's
+                sp.add(host_copy_bytes=copied)
+                leaves.append(leaf)
             return jax.tree_util.tree_unflatten(treedef, leaves), step
+
+    def _read_rows(self, entry: Dict[str, Any], dtype: np.dtype,
+                   shape: Tuple[int, ...], name: str,
+                   sp: Any) -> List[np.ndarray]:
+        """Every shard of a leaf read and CRC-checked, its rows a view of
+        the bytes read; together they must make the leaf's ``shape``."""
+        views = []
+        for sh in entry["shards"]:
+            data = self.mnt.read_file(sh["path"])
+            sp.add(bytes=len(data))
+            with obs.span("ckpt.crc32", bytes=len(data)):
+                crc = zlib.crc32(data) & 0xFFFFFFFF
+            if crc != sh["crc32"]:
+                raise IOError(f"checksum mismatch in {sh['path']}")
+            with obs.span("ckpt.decode", bytes=len(data)):
+                views.append(_rows(data, dtype, shape, sh["path"]))
+        if _joined_shape(views) != shape:
+            raise IOError(f"the shards of {name} hold rows of shape "
+                          f"{_joined_shape(views)}, not {shape}")
+        return views
+
+
+def _put_whole(views: List[np.ndarray], want: np.dtype,
+               put: Callable[[np.ndarray], Any]) -> Tuple[Any, int]:
+    """A host-described leaf: its rows copied into a host array of its own,
+    cast to ``want``, handed to ``put`` whole.  Returns ``put``'s result
+    and the bytes copied."""
+    with obs.span("ckpt.decode"):
+        arr = np.concatenate(views) if len(views) > 1 else views[0].copy()
+        arr = arr.astype(want, copy=False)
+    with obs.span("ckpt.put", bytes=arr.nbytes):
+        return put(arr), sum(v.nbytes for v in views)
+
+
+def _put_by_shard(views: List[np.ndarray], want: np.dtype,
+                  put: Callable[[np.ndarray], Any]) -> Tuple[Any, int]:
+    """A device-described leaf: each shard's rows handed to ``put`` as they
+    lie in the bytes read (copied only where a cast or the dtype's alignment
+    asks for it), the results joined along dim 0 in their own library.
+    Returns the leaf and the bytes of rows copied on the host."""
+    import jax
+    import jax.numpy as jnp
+    parts, copied = [], 0
+    for v in views:
+        if v.dtype != want or not v.flags.aligned:
+            copied += v.nbytes
+            with obs.span("ckpt.decode"):
+                v = v.astype(want)
+        parts.append(v)
+    stored = sum(v.nbytes for v in views)
+    with obs.span("ckpt.put", bytes=sum(p.nbytes for p in parts)):
+        outs = [put(p) for p in parts]
+        if len(outs) > 1 and isinstance(outs[0], jax.Array):
+            return jnp.concatenate(outs), copied
+        if len(outs) > 1:
+            return np.concatenate(outs), stored
+        leaf = outs[0]
+        if isinstance(leaf, np.ndarray) and np.may_share_memory(leaf,
+                                                                views[0]):
+            return leaf.copy(), stored
+        return leaf, copied

@@ -12,7 +12,8 @@ import pytest
 from repro import obs
 from repro.configs import get_arch
 from repro.core import CfsCluster
-from repro.storage.checkpoint import CheckpointManager, bytes_to_tensor
+from repro.storage.checkpoint import (CheckpointManager, bytes_to_tensor,
+                                      tensor_to_bytes)
 from repro.storage.datapipe import ShardReader, ShardWriter, hedged_read_file
 from repro.train import optimizer as opt
 from repro.train.trainer import Trainer, TrainerConfig
@@ -195,6 +196,158 @@ def test_restored_leaves_are_writable_and_their_own(cluster):
     got["a"][...] = 0
     for k in "bcd":
         assert got[k].tobytes() == tree[k].tobytes()
+
+
+def _on_device(tree):
+    """``like`` as a serving replica or ``Trainer.resume`` gives it."""
+    return jax.eval_shape(lambda: jax.tree.map(jnp.asarray, tree))
+
+
+def _host_copy_bytes(rec):
+    return obs.totals(rec.spans)["ckpt.restore"]["host_copy_bytes"]
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_restore_to_the_device_matches_decoding_each_shard(cluster, shards,
+                                                           dtype):
+    """With device-described leaves and ``put=jnp.asarray`` each leaf is a
+    ``jax.Array``, bit for bit what ``bytes_to_tensor`` of each shard,
+    concatenated, gives, and no row went through a host copy."""
+    dt = np.dtype(jnp.bfloat16) if dtype == "bfloat16" else np.dtype(dtype)
+    mnt = cluster.mount("train")
+    base = f"/ck_dev_{shards}_{dtype}"
+    tree = dict(_leaves(dt, seed=4),
+                e=np.random.RandomState(5).randn(8, 3).astype(dt))
+    CheckpointManager(mnt, base, shards=shards).save(3, tree)
+    manifest = json.loads(mnt.read_file(f"{base}/step_3/MANIFEST").decode())
+    with obs.recording() as rec:
+        got, step = CheckpointManager(cluster.mount("train"), base).restore(
+            _on_device(tree), put=jnp.asarray)
+    assert step == 3
+    assert _host_copy_bytes(rec) == 0
+    for k, v in tree.items():
+        entry = manifest["tensors"][k]
+        parts = [bytes_to_tensor(mnt.read_file(sh["path"]))
+                 for sh in entry["shards"]]
+        want = np.concatenate(parts, 0) if len(parts) > 1 else parts[0]
+        rows = v.shape[0] if v.ndim else 0
+        assert len(parts) == (shards if rows >= shards and rows % shards == 0
+                              else 1)
+        assert isinstance(got[k], jax.Array)
+        assert got[k].dtype == want.dtype == dt
+        assert got[k].shape == want.shape == v.shape
+        assert np.asarray(got[k]).tobytes() == want.tobytes() == v.tobytes()
+
+
+def test_restore_to_the_device_refuses_a_flipped_bit_before_put(cluster):
+    """A bit flipped in shard 1's rows raises IOError, and shard 0 of that
+    leaf never reaches ``put``; the leaves before it did."""
+    mnt = cluster.mount("train")
+    base = "/ck_dev_flip"
+    tree = _leaves(np.float32, seed=6)
+    CheckpointManager(mnt, base, shards=2).save(1, tree)
+    path = f"{base}/step_1/c.shard1"
+    at = mnt.stat(path)["size"] - 3
+    f = mnt.open(path, "r+")
+    f.seek(at)
+    byte = f.read(1)[0]
+    f.seek(at)
+    f.write(bytes([byte ^ 0x10]))
+    f.close()
+    put = []
+    with pytest.raises(IOError):
+        CheckpointManager(cluster.mount("train"), base).restore(
+            _on_device(tree), put=lambda arr: put.append(arr) or
+            jnp.asarray(arr))
+    # a (two shards) and b (one) went up; c did not
+    assert [p.tobytes() for p in put] == [
+        tree["a"][:3].tobytes(), tree["a"][3:].tobytes(), tree["b"].tobytes()]
+
+
+def test_host_puts_of_device_leaves_are_writable_and_their_own(cluster):
+    """A ``put`` that stays on the host, under device-described leaves,
+    still gives leaves that are writable and share no memory with the
+    bytes read or with one another."""
+    mnt = cluster.mount("train")
+    tree = _leaves(np.float32, seed=9)
+    CheckpointManager(mnt, "/ck_dev_own", shards=2).save(1, tree)
+    with obs.recording() as rec:
+        got, _ = CheckpointManager(mnt, "/ck_dev_own").restore(
+            _on_device(tree))
+    assert _host_copy_bytes(rec) == sum(v.nbytes for v in tree.values())
+    for k, x in got.items():
+        assert isinstance(x, np.ndarray) and x.flags.writeable
+        assert x.tobytes() == tree[k].tobytes()
+    got["a"][...] = 0
+    got["b"][...] = 0
+    for k in "cd":
+        assert got[k].tobytes() == tree[k].tobytes()
+
+
+@pytest.mark.parametrize("shape,dtype", [((6, 5), "float32"),
+                                         ((10,), "bfloat16"),
+                                         ((), "float32"),
+                                         ((3, 4, 7), "int8")])
+def test_shard_rows_start_at_a_multiple_of_64_bytes(cluster, shape, dtype):
+    """The header is padded with spaces: a saved shard's rows start at a
+    multiple of 64 bytes, and the file still decodes."""
+    dt = np.dtype(jnp.bfloat16) if dtype == "bfloat16" else np.dtype(dtype)
+    arr = np.asarray(np.random.RandomState(7).randn(*shape) * 50).astype(dt)
+    data = tensor_to_bytes(arr)
+    hlen = int.from_bytes(data[4:8], "little")
+    assert (8 + hlen) % 64 == 0
+    assert len(data) == 8 + hlen + arr.nbytes
+    assert bytes_to_tensor(data).tobytes() == arr.tobytes()
+    mnt = cluster.mount("train")
+    base = f"/ck_align_{dtype}_{len(shape)}"
+    CheckpointManager(mnt, base, shards=2).save(1, {"x": arr})
+    for name in mnt.readdir(f"{base}/step_1"):
+        if name != "MANIFEST":
+            shard = mnt.read_file(f"{base}/step_1/{name}")
+            assert (8 + int.from_bytes(shard[4:8], "little")) % 64 == 0
+
+
+def _unpadded(arr: np.ndarray) -> bytes:
+    """A shard file as written before headers were padded."""
+    header = json.dumps({"dtype": str(arr.dtype),
+                         "shape": list(arr.shape)}).encode()
+    return (b"RPT1" + len(header).to_bytes(4, "little") + header
+            + arr.tobytes())
+
+
+@pytest.mark.parametrize("on_device", [True, False])
+def test_a_shard_in_the_unpadded_layout_still_restores(cluster, on_device):
+    """Shards written before the header was padded (here at an odd offset,
+    so their float32 rows are not aligned) restore bit for bit, and the
+    device path counts their rows as copied on the host."""
+    import zlib
+    mnt = cluster.mount("train")
+    base = f"/ck_old_{on_device}"
+    tree = {"a": np.random.RandomState(8).randn(6, 5).astype(np.float32)}
+    mnt.mkdir(base)
+    mnt.mkdir(f"{base}/step_1")
+    shards = []
+    for k, part in enumerate(np.split(tree["a"], 2)):
+        data = _unpadded(part)
+        assert int.from_bytes(data[4:8], "little") % 2 == 1
+        path = f"{base}/step_1/a.shard{k}"
+        mnt.write_file(path, data)
+        shards.append({"path": path, "bytes": len(data),
+                       "crc32": zlib.crc32(data) & 0xFFFFFFFF})
+    manifest = {"step": 1, "tensors": {"a": {
+        "shards": shards, "dtype": "float32", "shape": [6, 5]}}}
+    mnt.write_file(f"{base}/step_1/MANIFEST", json.dumps(manifest).encode())
+    mnt.write_file(f"{base}/LATEST", b"1")
+    like = _on_device(tree) if on_device else {"a": np.zeros((6, 5),
+                                                             np.float32)}
+    with obs.recording() as rec:
+        got, step = CheckpointManager(cluster.mount("train"), base).restore(
+            like, put=jnp.asarray)
+    assert step == 1
+    assert isinstance(got["a"], jax.Array)
+    assert np.asarray(got["a"]).tobytes() == tree["a"].tobytes()
+    assert _host_copy_bytes(rec) == tree["a"].nbytes
 
 
 def test_elastic_restore_different_shard_count(cluster, data_volume):
