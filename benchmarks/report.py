@@ -1,59 +1,10 @@
-"""Generate the EXPERIMENTS.md §Dry-run/§Roofline tables.
+"""Print the client-mechanism tables: batched metadata RPCs, metadata
+sessions, async metadata and QoS, each measured on the simulated cluster.
 
-Baseline HLOs (results/dryrun_baseline/) are re-analyzed with the FINAL
-parser so baseline-vs-optimized deltas reflect CODE changes only, never
-parser changes.
-
-    PYTHONPATH=src python -m benchmarks.report > results/roofline_report.md
+    PYTHONPATH=src python -m benchmarks.report
 """
 
 from __future__ import annotations
-
-import json
-from pathlib import Path
-
-from repro.configs import get_arch, get_shape
-from repro.launch.roofline import (PEAK_FLOPS, fold_totals,
-                                   model_flops_per_device, roofline_terms)
-
-ROOT = Path(__file__).resolve().parents[1] / "results"
-
-
-def analyze_dir(d: Path):
-    out = {}
-    for p in sorted(d.glob("*.json")):
-        r = json.loads(p.read_text())
-        key = (r["arch"], r["shape"], r["mesh"])
-        if not r.get("ok"):
-            out[key] = None
-            continue
-        hlo_path = d / f"{r['arch']}__{r['shape']}__{r['mesh']}.hlo.txt"
-        if hlo_path.exists():
-            totals = fold_totals(hlo_path.read_text())
-            rf = roofline_terms(totals)
-        else:
-            totals = r.get("totals", {})
-            rf = r.get("roofline", {})
-        out[key] = {"totals": totals, "roofline": rf,
-                    "mem": r.get("memory_analysis"),
-                    "compile_s": r.get("compile_s", 0)}
-    return out
-
-
-def fmt_cell(arch, shape, rec):
-    if rec is None:
-        return None
-    t, rf = rec["totals"], rec["roofline"]
-    mf = model_flops_per_device(get_arch(arch), get_shape(shape))
-    ideal = mf / PEAK_FLOPS
-    bound = rf["bound_s"]
-    ratio = mf / t["dot_flops"] if t.get("dot_flops") else 0
-    return {
-        "compute": rf["compute_s"], "memory": rf["memory_s"],
-        "coll": rf["collective_s"], "dom": rf["dominant"],
-        "ideal": ideal, "frac": ideal / bound if bound else 0,
-        "mhr": ratio,
-    }
 
 
 def meta_batch_report(n_files: int = 64) -> None:
@@ -244,51 +195,6 @@ def main() -> None:
     meta_session_report()
     meta_async_report()
     qos_report()
-    final = analyze_dir(ROOT / "dryrun")
-    base = analyze_dir(ROOT / "dryrun_baseline")
-
-    print("## §Roofline — single-pod (16x16) per-device terms, final vs "
-          "paper-faithful baseline\n")
-    print("| arch | shape | compute_s | memory_s | collective_s | dominant |"
-          " MODEL/HLO | roofline frac | baseline frac |")
-    print("|---|---|---|---|---|---|---|---|---|")
-    for (arch, shape, mesh), rec in sorted(final.items()):
-        if mesh != "pod16x16":
-            continue
-        c = fmt_cell(arch, shape, rec)
-        b = fmt_cell(arch, shape, base.get((arch, shape, mesh)))
-        if c is None:
-            print(f"| {arch} | {shape} | FAIL | | | | | | |")
-            continue
-        bf = f"{b['frac']*100:.2f}%" if b else "-"
-        print(f"| {arch} | {shape} | {c['compute']:.3f} | {c['memory']:.3f} |"
-              f" {c['coll']:.3f} | {c['dom']} | {c['mhr']:.2f} |"
-              f" {c['frac']*100:.2f}% | {bf} |")
-
-    print("\n## §Dry-run — compile status (both meshes)\n")
-    print("| arch | shape | 16x16 | 2x16x16 | compile_s (single/multi) |")
-    print("|---|---|---|---|---|")
-    seen = set()
-    for (arch, shape, mesh), rec in sorted(final.items()):
-        if (arch, shape) in seen:
-            continue
-        seen.add((arch, shape))
-        s = final.get((arch, shape, "pod16x16"))
-        m = final.get((arch, shape, "pod2x16x16"))
-        print(f"| {arch} | {shape} | {'OK' if s else 'FAIL'} |"
-              f" {'OK' if m else 'FAIL'} |"
-              f" {s['compile_s'] if s else '-'} / {m['compile_s'] if m else '-'} |")
-
-    # aggregate
-    fracs = [fmt_cell(a, sh, r)["frac"] for (a, sh, me), r in final.items()
-             if me == "pod16x16" and r]
-    bfr = [fmt_cell(a, sh, r)["frac"] for (a, sh, me), r in base.items()
-           if me == "pod16x16" and r]
-    import statistics
-    if fracs and bfr:
-        print(f"\nmedian roofline fraction: final "
-              f"{statistics.median(fracs)*100:.2f}% vs baseline "
-              f"{statistics.median(bfr)*100:.2f}%  (n={len(fracs)})")
 
 
 if __name__ == "__main__":

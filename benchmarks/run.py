@@ -1,6 +1,6 @@
 """Benchmark driver — one suite per paper table/figure.
 
-    PYTHONPATH=src python -m benchmarks.run [--suite mdtest|largefile|smallfile|expansion|roofline]
+    PYTHONPATH=src python -m benchmarks.run [--suite mdtest|largefile|smallfile|expansion|hotset|dataloader|qos]
                                             [--smoke]
 
 Prints CSV rows (test,system,clients,procs,ops,sim_iops,...,p99_us,...),
@@ -12,14 +12,12 @@ largefile smoke includes the read-path A/B rows (SeqRead with a nonzero
 CFS_READ_WINDOW, RandRead with an injected straggler replica), so the
 windowed-read and hedge paths are exercised on every push.  Smoke output
 goes to side paths (results/bench/*.smoke.csv, BENCH_*.smoke.json under
-results/bench/) and never clobbers the committed full-sweep baselines.
-The roofline suite summarizes the dry-run artifacts in results/dryrun/."""
+results/bench/) and never clobbers the committed full-sweep baselines."""
 
 from __future__ import annotations
 
 import argparse
 import json
-import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -35,63 +33,30 @@ def run_suite(name: str, rows: list, smoke: bool) -> list:
     return mod.run(rows, smoke=smoke)
 
 
-def roofline_summary(rows: list) -> None:
-    dry = ROOT / "results" / "dryrun"
-    rows.append("# arch,shape,mesh,ok,compute_s,memory_s,collective_s,"
-                "dominant,model_hlo_ratio")
-    from repro.configs import get_arch, get_shape
-    from repro.launch.roofline import model_flops_per_device
-    for p in sorted(dry.glob("*.json")):
-        r = json.loads(p.read_text())
-        if not r.get("ok"):
-            rows.append(f"{r['arch']},{r['shape']},{r['mesh']},FAIL,,,,,")
-            continue
-        rf = r.get("roofline", {})
-        tot = r.get("totals", {})
-        ratio = ""
-        if tot.get("dot_flops"):
-            mf = model_flops_per_device(get_arch(r["arch"]),
-                                        get_shape(r["shape"]))
-            ratio = f"{mf / tot['dot_flops']:.3f}"
-        rows.append(
-            f"{r['arch']},{r['shape']},{r['mesh']},OK,"
-            f"{rf.get('compute_s', 0):.4f},{rf.get('memory_s', 0):.4f},"
-            f"{rf.get('collective_s', 0):.4f},{rf.get('dominant', '?')},"
-            f"{ratio}")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--suite", default="all",
                     choices=["all", "mdtest", "largefile", "smallfile",
-                             "expansion", "hotset", "dataloader", "qos",
-                             "roofline"])
+                             "expansion", "hotset", "dataloader", "qos"])
     ap.add_argument("--smoke", action="store_true",
                     help="tiny op counts (<30 s total) for CI drift checks")
     args = ap.parse_args()
     RESULTS.mkdir(parents=True, exist_ok=True)
 
     suites = (["mdtest", "largefile", "smallfile", "expansion", "hotset",
-               "dataloader", "qos", "roofline"]
+               "dataloader", "qos"]
               if args.suite == "all" else [args.suite])
     from .common import HEADER
     for suite in suites:
-        rows: list = []
-        json_results: list = []
         print(f"=== suite: {suite} ===")
-        if suite == "roofline":
-            roofline_summary(rows)
-        else:
-            rows.insert(0, HEADER)
-            json_results = run_suite(suite, rows, args.smoke)
+        rows: list = [HEADER]
+        json_results = run_suite(suite, rows, args.smoke)
         for row in rows:
             print(row)
         # smoke runs go to a side path: they must never clobber the
         # committed full-sweep baselines (csv + BENCH_*.json)
         suffix = ".smoke.csv" if args.smoke else ".csv"
         (RESULTS / f"{suite}{suffix}").write_text("\n".join(rows) + "\n")
-        if suite == "roofline":
-            continue            # roofline has no BenchResult trajectory
         payload = {"suite": suite, "smoke": args.smoke,
                    "results": json_results}
         name = f"BENCH_{suite}.smoke.json" if args.smoke else f"BENCH_{suite}.json"

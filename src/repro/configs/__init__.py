@@ -1,5 +1,4 @@
-from .base import ArchConfig, SHAPES, ShapeConfig, runnable_cells
-from .registry import ARCHS, ARCH_NAMES, all_cells, get_arch, get_shape
+from .base import ArchConfig
+from .registry import ARCHS, ARCH_NAMES, get_arch
 
-__all__ = ["ArchConfig", "ShapeConfig", "SHAPES", "ARCHS", "ARCH_NAMES",
-           "get_arch", "get_shape", "all_cells", "runnable_cells"]
+__all__ = ["ArchConfig", "ARCHS", "ARCH_NAMES", "get_arch"]

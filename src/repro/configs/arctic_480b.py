@@ -20,8 +20,7 @@ CONFIG = ArchConfig(
     top_k=2,
     d_expert=4864,
     dense_residual=True,
-    # 960 GB of bf16 params: fp32 Adam is impossible on one pod; bf16 moments
-    # + no fp32 master copy (DESIGN.md §Memory-driven config decisions)
+    # 960 GB of bf16 params: bf16 moments, no fp32 master copy, to fit HBM
     optimizer_moment_dtype="bfloat16",
     use_master_weights=False,
     notes="128e top-2 + dense residual branch; experts sharded 8-per-group"

@@ -1,17 +1,14 @@
-"""Architecture + shape configuration system.
-
-Every assigned architecture is a frozen ``ArchConfig``; every workload shape
-is a ``ShapeConfig``.  The dry-run grid is the cross product (minus the
-documented skips, see ``runnable_cells``).
+"""Architecture configuration: every assigned architecture is a frozen
+``ArchConfig``, with its parameter count and a ``reduced()`` width for CPU
+tests.
 """
 
 from __future__ import annotations
 
-import dataclasses
-from dataclasses import dataclass, field, replace
-from typing import Dict, List, Optional, Tuple
+from dataclasses import dataclass, replace
+from typing import Optional
 
-__all__ = ["ArchConfig", "ShapeConfig", "SHAPES", "runnable_cells"]
+__all__ = ["ArchConfig"]
 
 
 @dataclass(frozen=True)
@@ -71,7 +68,6 @@ class ArchConfig:
 
     # serving
     kv_cache_dtype: str = "bfloat16"        # "int8" where HBM requires it
-    kv_cache_dtype_decode_32k: Optional[str] = None  # per-cell override
 
     notes: str = ""
 
@@ -79,7 +75,7 @@ class ArchConfig:
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
 
-    # ---- parameter counting (for MODEL_FLOPS and memory budgeting) --------
+    # ---- parameter counting ------------------------------------------------
     def _attn_params(self) -> int:
         d, H = self.d_model, self.n_heads
         if self.kv_lora_rank:
@@ -126,17 +122,6 @@ class ArchConfig:
                     + (L - k) * (attn + self._moe_layer_params(held) + norms))
         return emb + d + L * (attn + mlp_dense + norms)
 
-    def active_param_count(self) -> int:
-        """Per-token active parameters (= dense count unless MoE)."""
-        if self.family != "moe":
-            return self.param_count()
-        d, f, L, k = self.d_model, self.d_ff, self.n_layers, self.first_k_dense
-        attn, norms = self._attn_params(), 2 * d
-        emb = self.vocab * d * (1 if self.tie_embeddings else 2)
-        return (emb + d + k * (attn + 3 * d * f + norms)
-                + (L - k) * (attn + self._moe_layer_params(self.top_k)
-                             + norms))
-
     # ---- reduced config for CPU smoke tests --------------------------------
     def reduced(self) -> "ArchConfig":
         experts = min(self.n_experts, max(4, self.top_k + 2))
@@ -163,35 +148,3 @@ class ArchConfig:
             swa_window=min(self.swa_window, 64) if self.swa_window else 0,
         )
 
-
-@dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                    # "train" | "prefill" | "decode"
-
-
-SHAPES: Dict[str, ShapeConfig] = {
-    "train_4k": ShapeConfig("train_4k", 4_096, 256, "train"),
-    "prefill_32k": ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    "decode_32k": ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    "long_500k": ShapeConfig("long_500k", 524_288, 1, "decode"),
-}
-
-# archs whose attention is sub-quadratic / state-based and can run long_500k
-_LONG_OK = {"rwkv6-1.6b", "zamba2-7b", "mixtral-8x22b"}
-
-
-def runnable_cells(arch_names: List[str]) -> List[Tuple[str, str]]:
-    """The dry-run grid: every (arch, shape) minus the documented skips.
-
-    ``long_500k`` needs sub-quadratic attention — skipped for pure
-    full-attention archs (see DESIGN.md §Shape-cell skips)."""
-    cells = []
-    for a in arch_names:
-        for s in ("train_4k", "prefill_32k", "decode_32k"):
-            cells.append((a, s))
-        if a in _LONG_OK:
-            cells.append((a, "long_500k"))
-    return cells

@@ -2,8 +2,8 @@
 
 Early-fusion VLM: one decoder over a mixed text+VQ-image token stream.
 48L d_model=8192 64H (GQA kv=8) d_ff=22016 vocab=65536, qk-norm
-(chameleon's stability fix).  The VQ image tokenizer is a STUB:
-``input_specs()`` provides precomputed mixed token ids.
+(chameleon's stability fix).  The VQ image tokenizer is a stub: the
+backbone takes mixed token ids.
 """
 
 from .base import ArchConfig

@@ -1,9 +1,9 @@
 """musicgen-large — [arXiv:2306.05284; hf]
 
 Decoder-only transformer over EnCodec tokens: 48L d_model=2048 32H (kv=32)
-d_ff=8192 vocab=2048.  The EnCodec/text-conditioning frontend is a STUB per
-the assignment: ``input_specs()`` provides precomputed token ids (the
-4-codebook delay pattern collapsed to a single stream for the backbone).
+d_ff=8192 vocab=2048.  The EnCodec/text-conditioning frontend is a stub:
+the backbone takes token ids (the 4-codebook delay pattern collapsed to a
+single stream).
 """
 
 from .base import ArchConfig

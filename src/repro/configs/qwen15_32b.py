@@ -17,9 +17,6 @@ CONFIG = ArchConfig(
     vocab=152064,
     qkv_bias=True,
     rope_theta=1_000_000.0,
-    # bf16 KV cache at decode_32k = 5.5 TB > one pod's 4 TB HBM -> int8 KV
-    # for that cell (DESIGN.md §Memory-driven config decisions)
-    kv_cache_dtype_decode_32k="int8",
     notes="MHA (kv=40); fp32 Adam moments would be 384 GB -> ZeRO-1 shards"
           " them over the data axis",
 )

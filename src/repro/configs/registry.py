@@ -3,7 +3,7 @@
 from typing import Dict, List
 
 from .arctic_480b import CONFIG as _arctic
-from .base import ArchConfig, SHAPES, ShapeConfig, runnable_cells
+from .base import ArchConfig
 from .chameleon_34b import CONFIG as _chameleon
 from .codeqwen15_7b import CONFIG as _codeqwen
 from .minicpm_2b import CONFIG as _minicpm
@@ -31,10 +31,3 @@ def get_arch(name: str) -> ArchConfig:
         raise KeyError(f"unknown arch {name!r}; available: {ARCH_NAMES}")
     return ARCHS[name]
 
-
-def get_shape(name: str) -> ShapeConfig:
-    return SHAPES[name]
-
-
-def all_cells():
-    return runnable_cells(ARCH_NAMES)

@@ -2,8 +2,8 @@
 
 Dispatch policy: on TPU the Pallas kernels run compiled; everywhere else
 they run in interpret mode (tests) while the MODELS lower through the
-``ref`` implementations (same math, same memory shape) — so the dry-run's
-HLO reflects the production structure and the kernels stay validated.
+``ref`` implementations (same math, same memory shape), so the models'
+programs keep the production structure and the kernels stay validated.
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Pure-jnp reference implementations (oracles) for every Pallas kernel.
 
 These are ALSO the implementations the models lower through on CPU: they are
-memory-bounded (blockwise flash attention, chunked scans) so the dry-run's
-``memory_analysis()`` reflects a production-shaped program, and the Pallas
-kernels are validated against them in interpret mode.
+memory-bounded (blockwise flash attention, chunked scans) so the models'
+programs keep a production memory shape, and the Pallas kernels are
+validated against them in interpret mode.
 """
 
 from __future__ import annotations

@@ -7,15 +7,14 @@
     logits, cache = api.decode(params, token, cache, cache_len)
     cache_specs   = api.cache_spec(batch, smax, kv_dtype)   # ShapeDtypeStructs
 
-musicgen-large and chameleon-34b reuse the dense-transformer backbone —
-their modality frontends are stubs per the assignment: ``input_specs()``
-provides precomputed token ids.
+musicgen-large and chameleon-34b reuse the dense-transformer backbone:
+their modality frontends are stubs, and the backbone takes token ids.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict
 
 import jax
 import jax.numpy as jnp
@@ -77,26 +76,3 @@ def get_model(cfg: ArchConfig) -> ModelApi:
             _sds(transformer.kv_cache_spec(cfg, batch, smax, kv)),
     )
 
-
-def input_specs(cfg: ArchConfig, shape, mode: Optional[str] = None
-                ) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct stand-ins for every model input of one dry-run cell.
-
-    For [audio]/[vlm] archs the frontend is a stub — the specs ARE the
-    precomputed token stream the frontend would produce."""
-    mode = mode or shape.kind
-    b, t = shape.global_batch, shape.seq_len
-    tok = jax.ShapeDtypeStruct((b, t), jnp.int32)
-    if mode == "train":
-        return {"tokens": tok, "labels": jax.ShapeDtypeStruct((b, t), jnp.int32)}
-    if mode == "prefill":
-        return {"tokens": tok}
-    if mode == "decode":
-        return {"token": jax.ShapeDtypeStruct((b, 1), jnp.int32)}
-    raise ValueError(mode)
-
-
-def kv_dtype_for_cell(cfg: ArchConfig, shape_name: str) -> str:
-    if shape_name == "decode_32k" and cfg.kv_cache_dtype_decode_32k:
-        return cfg.kv_cache_dtype_decode_32k
-    return cfg.kv_cache_dtype

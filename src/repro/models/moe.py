@@ -225,15 +225,6 @@ def moe_block(cfg: ArchConfig, p: Params, x: jnp.ndarray,
     return out
 
 
-def load_balance_loss(cfg: ArchConfig, gate_probs: jnp.ndarray,
-                      top_e: jnp.ndarray) -> jnp.ndarray:
-    """Switch-style auxiliary loss (exposed for the training loop)."""
-    E = cfg.n_experts
-    me = jnp.mean(jax.nn.one_hot(top_e[..., 0], E), axis=0)
-    pe = jnp.mean(gate_probs, axis=0)
-    return E * jnp.sum(me * pe)
-
-
 # ------------------------------------------------------------ expert share
 #
 # One chip's share of an expert-parallel layer (DeepSeek-V3 style): the

@@ -3,8 +3,8 @@
 A fixed pool of ``batch`` sequence slots; finished sequences are replaced by
 queued requests (prefill into the free slot's cache region is approximated
 by re-prefilling the whole batch only when a slot JOINS — for the CPU
-example this keeps the code simple while exercising prefill+decode+KV reuse;
-the dry-run decode cell is the production shape).
+example this keeps the code simple while exercising prefill+decode+KV
+reuse).
 """
 
 from __future__ import annotations

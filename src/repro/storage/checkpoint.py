@@ -9,8 +9,8 @@ Layout on the volume:
 
 Crash safety: data files first, MANIFEST second, LATEST last — a crash at
 any point leaves the previous checkpoint loadable (tested with injected
-crashes).  Every tensor carries a CRC32 in the manifest, verified on load
-(the device-side Pallas ``checksum`` kernel plays this role on TPU).
+crashes).  Every shard file carries a CRC32 in the manifest; restore checks
+it on the host, over the bytes read, before the leaf reaches ``put``.
 
 Elasticity: tensors are split into ``shards`` along dim 0 where possible,
 and a leaf is whatever its shards hold, joined along dim 0, so a
@@ -19,10 +19,9 @@ device_put with the new mesh's shardings).
 
 A shard file is ``RPT1``, the header's length, a JSON header padded with
 spaces so that the rows start at a multiple of 64 bytes, then the rows.
-Restore takes the rows as a view of the bytes read: a leaf described as a
-device array goes to ``put`` shard by shard and is joined on the device,
-with no host copy of its rows; a leaf described by a host array is
-assembled in a host array of its own.
+Restore takes the rows as a view of the bytes read: every leaf goes to
+``put`` shard by shard and is joined where ``put`` left its shards, so a
+leaf put on the device is joined there, with no host copy of its rows.
 """
 
 from __future__ import annotations
@@ -38,8 +37,7 @@ from .. import obs
 from ..core.client import NotFound
 from ..core.fs import CfsMount
 
-__all__ = ["CheckpointManager", "InjectedCrash", "tensor_to_bytes",
-           "bytes_to_tensor"]
+__all__ = ["CheckpointManager", "InjectedCrash", "tensor_to_bytes"]
 
 _MAGIC = b"RPT1"
 _ROW_ALIGN = 64   # a shard's rows start at a multiple of this
@@ -58,12 +56,6 @@ def tensor_to_bytes(arr: np.ndarray) -> bytes:
     header += b" " * (-(8 + len(header)) % _ROW_ALIGN)
     raw = np.ascontiguousarray(arr).tobytes()
     return (_MAGIC + len(header).to_bytes(4, "little") + header + raw)
-
-
-def bytes_to_tensor(data: bytes) -> np.ndarray:
-    dtype, shape, offset = _parse_header(data)
-    return np.frombuffer(data, dtype=dtype,
-                         offset=offset).reshape(shape).copy()
 
 
 def _parse_header(data: bytes) -> Tuple[np.dtype, Tuple[int, ...], int]:
@@ -193,15 +185,13 @@ class CheckpointManager:
         (a device transfer, say), and the leaf is done before the next one
         is read, so the host holds one leaf's shard bytes at a time.
 
-        A leaf described by a device array (``jax.ShapeDtypeStruct`` or
-        ``jax.Array``) goes to ``put`` shard by shard, each shard's rows a
-        view of the bytes read, and the results are joined along dim 0 in
-        their own library (on the device for ``jax.Array``s).  A leaf
-        described otherwise is assembled in a host array of its own, handed
-        to ``put`` whole.  The ``ckpt.restore`` span counts in
-        ``host_copy_bytes`` the stored bytes of rows that went through a
-        host copy: a host-described leaf, a view not aligned to its dtype,
-        a cast, or a host ``put`` result joined or copied off the bytes."""
+        Every leaf goes to ``put`` shard by shard, each shard's rows a view
+        of the bytes read, and the results are joined along dim 0 in their
+        own library (on the device for ``jax.Array``s).  The
+        ``ckpt.restore`` span counts in ``host_copy_bytes`` the stored bytes
+        of rows that went through a host copy: a view not aligned to its
+        dtype, a cast, or a host ``put`` result joined or copied off the
+        bytes."""
         import jax
         with obs.span("ckpt.restore") as sp:
             step = self.latest_step() if step is None else step
@@ -219,10 +209,7 @@ class CheckpointManager:
                 dtype, shape = np.dtype(entry["dtype"]), tuple(entry["shape"])
                 want = np.dtype(like.dtype) if hasattr(like, "dtype") else dtype
                 views = self._read_rows(entry, dtype, shape, name, sp)
-                if isinstance(like, (jax.ShapeDtypeStruct, jax.Array)):
-                    leaf, copied = _put_by_shard(views, want, put)
-                else:
-                    leaf, copied = _put_whole(views, want, put)
+                leaf, copied = _put(views, want, put)
                 del views  # this leaf's shard bytes, before the next leaf's
                 sp.add(host_copy_bytes=copied)
                 leaves.append(leaf)
@@ -249,24 +236,13 @@ class CheckpointManager:
         return views
 
 
-def _put_whole(views: List[np.ndarray], want: np.dtype,
-               put: Callable[[np.ndarray], Any]) -> Tuple[Any, int]:
-    """A host-described leaf: its rows copied into a host array of its own,
-    cast to ``want``, handed to ``put`` whole.  Returns ``put``'s result
-    and the bytes copied."""
-    with obs.span("ckpt.decode"):
-        arr = np.concatenate(views) if len(views) > 1 else views[0].copy()
-        arr = arr.astype(want, copy=False)
-    with obs.span("ckpt.put", bytes=arr.nbytes):
-        return put(arr), sum(v.nbytes for v in views)
-
-
-def _put_by_shard(views: List[np.ndarray], want: np.dtype,
-                  put: Callable[[np.ndarray], Any]) -> Tuple[Any, int]:
-    """A device-described leaf: each shard's rows handed to ``put`` as they
-    lie in the bytes read (copied only where a cast or the dtype's alignment
-    asks for it), the results joined along dim 0 in their own library.
-    Returns the leaf and the bytes of rows copied on the host."""
+def _put(views: List[np.ndarray], want: np.dtype,
+         put: Callable[[np.ndarray], Any]) -> Tuple[Any, int]:
+    """One leaf: each shard's rows handed to ``put`` as they lie in the
+    bytes read (copied only where a cast or the dtype's alignment asks for
+    it), the results joined along dim 0 in their own library, and a host
+    result that aliases the bytes read copied.  Returns the leaf and the
+    bytes of rows copied on the host."""
     import jax
     import jax.numpy as jnp
     parts, copied = [], 0

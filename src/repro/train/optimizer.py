@@ -1,7 +1,7 @@
 """Optimizer: AdamW with configurable moment dtype + LR schedules.
 
-* moments in fp32 by default, bf16 for the huge MoE archs (config flag) —
-  the memory budgeting decision documented in DESIGN.md;
+* moments in fp32 by default; the huge MoE archs keep bf16 moments and
+  no fp32 master copy to fit HBM (config flags);
 * optional fp32 master weights (disabled for arctic);
 * WSD (warmup-stable-decay) schedule for minicpm, cosine for the rest;
 * global-norm gradient clipping.

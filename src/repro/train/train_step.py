@@ -2,7 +2,7 @@
 
 Microbatching (gradient accumulation) happens OUTSIDE via the batch shape;
 remat inside the model keeps activations O(1) in depth.  The same function
-is lowered for the dry-run and executed for the CPU examples.
+runs on the chip (``chip_smoke.py``) and in the CPU examples.
 """
 
 from __future__ import annotations

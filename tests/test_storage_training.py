@@ -12,7 +12,7 @@ import pytest
 from repro import obs
 from repro.configs import get_arch
 from repro.core import CfsCluster
-from repro.storage.checkpoint import (CheckpointManager, bytes_to_tensor,
+from repro.storage.checkpoint import (CheckpointManager, _parse_header,
                                       tensor_to_bytes)
 from repro.storage.datapipe import ShardReader, ShardWriter, hedged_read_file
 from repro.train import optimizer as opt
@@ -39,6 +39,13 @@ def data_volume(cluster):
         w.add_document(doc)
     w.finish()
     return mnt
+
+
+def bytes_to_tensor(data: bytes) -> np.ndarray:
+    """The oracle: a tensor file decoded into a host array of its own."""
+    dtype, shape, offset = _parse_header(data)
+    return np.frombuffer(data, dtype=dtype,
+                         offset=offset).reshape(shape).copy()
 
 
 def make_trainer(cluster, mnt, base="/ckpt", seed=0):
@@ -156,7 +163,7 @@ def test_restore_casts_to_the_dtype_asked_for(cluster):
 def test_restore_refuses_a_flipped_bit_before_put(cluster, where):
     """A bit flipped in a shard's header or in its rows, or bytes after its
     rows, raise IOError, and the leaf is never handed to ``put``; the
-    leaves before it were."""
+    leaves before it were, shard by shard."""
     mnt = cluster.mount("train")
     base = f"/ck_flip_{where}"
     tree = _leaves(np.float32, seed=2)
@@ -180,7 +187,9 @@ def test_restore_refuses_a_flipped_bit_before_put(cluster, where):
         CheckpointManager(cluster.mount("train"), base).restore(
             {k: np.zeros(v.shape, v.dtype) for k, v in tree.items()},
             put=lambda arr: put.append(arr) or arr)
-    assert len(put) == 1 and put[0].tobytes() == tree["a"].tobytes()
+    # a's two shards went up, b's did not
+    assert len(put) == 2
+    assert np.concatenate(put).tobytes() == tree["a"].tobytes()
 
 
 def test_restored_leaves_are_writable_and_their_own(cluster):
@@ -205,6 +214,32 @@ def _on_device(tree):
 
 def _host_copy_bytes(rec):
     return obs.totals(rec.spans)["ckpt.restore"]["host_copy_bytes"]
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_host_described_leaves_go_to_put_by_shard(cluster, shards):
+    """Leaves described by host arrays take the device leaves' rule: with
+    ``put=jnp.asarray`` each is a ``jax.Array``, bit for bit what
+    ``bytes_to_tensor`` of each shard, concatenated, gives, and no row went
+    through a host copy."""
+    mnt = cluster.mount("train")
+    base = f"/ck_host_{shards}"
+    tree = dict(_leaves(np.float32, seed=10),
+                e=np.random.RandomState(11).randn(8, 3).astype(np.float32))
+    CheckpointManager(mnt, base, shards=shards).save(1, tree)
+    manifest = json.loads(mnt.read_file(f"{base}/step_1/MANIFEST").decode())
+    with obs.recording() as rec:
+        got, _ = CheckpointManager(cluster.mount("train"), base).restore(
+            {k: np.zeros(v.shape, v.dtype) for k, v in tree.items()},
+            put=jnp.asarray)
+    assert _host_copy_bytes(rec) == 0
+    for k, v in tree.items():
+        parts = [bytes_to_tensor(mnt.read_file(sh["path"]))
+                 for sh in manifest["tensors"][k]["shards"]]
+        want = np.concatenate(parts, 0) if len(parts) > 1 else parts[0]
+        assert isinstance(got[k], jax.Array)
+        assert got[k].dtype == want.dtype and got[k].shape == v.shape
+        assert np.asarray(got[k]).tobytes() == want.tobytes() == v.tobytes()
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
