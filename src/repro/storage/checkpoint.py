@@ -10,7 +10,8 @@ Layout on the volume:
 Crash safety: data files first, MANIFEST second, LATEST last — a crash at
 any point leaves the previous checkpoint loadable (tested with injected
 crashes).  Every shard file carries a CRC32 in the manifest; restore checks
-it on the host, over the bytes read, before the leaf reaches ``put``.
+it on the host, over the bytes read, before the leaf reaches ``put``.  The
+CRC of each shard runs on a worker thread while the next shard is read.
 
 Elasticity: tensors are split into ``shards`` along dim 0 where possible,
 and a leaf is whatever its shards hold, joined along dim 0, so a
@@ -29,6 +30,7 @@ from __future__ import annotations
 import json
 import math
 import zlib
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -180,10 +182,13 @@ class CheckpointManager:
                 put: Callable[[np.ndarray], Any] = lambda arr: arr
                 ) -> Tuple[Any, int]:
         """Restore into the structure and dtypes of ``tree_like`` (arrays or
-        ``ShapeDtypeStruct``s).  Each leaf's shards are read, CRC-checked
-        and their headers checked before any of them is handed to ``put``
-        (a device transfer, say), and the leaf is done before the next one
-        is read, so the host holds one leaf's shard bytes at a time.
+        ``ShapeDtypeStruct``s).  Shards are read in leaf order on the
+        calling thread, and each shard's CRC32 is computed on one worker
+        thread while the next shard is read.  A leaf is finished once the
+        first shard of the next leaf has been read (or none is left): each
+        of its shards' CRCs is checked and its header checked before any of
+        them is handed to ``put`` (a device transfer, say).  So the host
+        holds one leaf's shard bytes and one more shard at a time.
 
         Every leaf goes to ``put`` shard by shard, each shard's rows a view
         of the bytes read, and the results are joined along dim 0 in their
@@ -191,9 +196,12 @@ class CheckpointManager:
         ``ckpt.restore`` span counts in ``host_copy_bytes`` the stored bytes
         of rows that went through a host copy: a view not aligned to its
         dtype, a cast, or a host ``put`` result joined or copied off the
-        bytes."""
+        bytes.  Each ``ckpt.crc32`` span times the wait for one shard's
+        CRC and counts ``ready``: 1 where it was done before it was asked
+        for; the CRC's own work is the worker's ``ckpt.crc32_work``."""
         import jax
-        with obs.span("ckpt.restore") as sp:
+        with obs.span("ckpt.restore") as sp, \
+                ThreadPoolExecutor(max_workers=1) as crc_worker:
             step = self.latest_step() if step is None else step
             if step is None:
                 raise NotFound("no checkpoint")
@@ -202,38 +210,65 @@ class CheckpointManager:
                 self.mnt.read_file(f"{d}/MANIFEST").decode())
             flat, treedef = jax.tree_util.tree_flatten_with_path(tree_like)
             sp.add(host_copy_bytes=0)
-            leaves = []
-            for path, like in flat:
-                name = _leaf_name(path)
-                entry = manifest["tensors"][name]
+            names = [_leaf_name(path) for path, _ in flat]
+            leaves: List[Any] = []
+
+            def finish(j: int, shards: List[_Read]) -> Any:
+                """Leaf ``j`` from its ``shards`` read, each CRC- and
+                header-checked before any goes to ``put``."""
+                entry = manifest["tensors"][names[j]]
                 dtype, shape = np.dtype(entry["dtype"]), tuple(entry["shape"])
+                like = flat[j][1]
                 want = np.dtype(like.dtype) if hasattr(like, "dtype") else dtype
-                views = self._read_rows(entry, dtype, shape, name, sp)
+                views = _checked_rows(shards, dtype, shape, names[j])
                 leaf, copied = _put(views, want, put)
-                del views  # this leaf's shard bytes, before the next leaf's
                 sp.add(host_copy_bytes=copied)
-                leaves.append(leaf)
+                return leaf
+
+            read: List[_Read] = []
+            for i, name in enumerate(names):
+                for k, sh in enumerate(manifest["tensors"][name]["shards"]):
+                    data = self.mnt.read_file(sh["path"])
+                    sp.add(bytes=len(data))
+                    crc = crc_worker.submit(_crc32, data)
+                    if k == 0 and i > 0:
+                        leaves.append(finish(i - 1, read))
+                        read = []
+                    read.append((sh, data, crc))
+            if names:
+                leaves.append(finish(len(names) - 1, read))
             return jax.tree_util.tree_unflatten(treedef, leaves), step
 
-    def _read_rows(self, entry: Dict[str, Any], dtype: np.dtype,
-                   shape: Tuple[int, ...], name: str,
-                   sp: Any) -> List[np.ndarray]:
-        """Every shard of a leaf read and CRC-checked, its rows a view of
-        the bytes read; together they must make the leaf's ``shape``."""
-        views = []
-        for sh in entry["shards"]:
-            data = self.mnt.read_file(sh["path"])
-            sp.add(bytes=len(data))
-            with obs.span("ckpt.crc32", bytes=len(data)):
-                crc = zlib.crc32(data) & 0xFFFFFFFF
-            if crc != sh["crc32"]:
-                raise IOError(f"checksum mismatch in {sh['path']}")
-            with obs.span("ckpt.decode", bytes=len(data)):
-                views.append(_rows(data, dtype, shape, sh["path"]))
-        if _joined_shape(views) != shape:
-            raise IOError(f"the shards of {name} hold rows of shape "
-                          f"{_joined_shape(views)}, not {shape}")
-        return views
+
+# a shard read: its manifest entry, its bytes and its CRC32 to come
+_Read = Tuple[Dict[str, Any], bytes, Future]
+
+
+def _crc32(data: bytes) -> int:
+    """A shard's CRC32, on the restore's worker thread: timed there as the
+    root span ``ckpt.crc32_work``, outside the restore's own spans."""
+    with obs.span("ckpt.crc32_work", bytes=len(data)):
+        return zlib.crc32(data) & 0xFFFFFFFF
+
+
+def _checked_rows(read: List[_Read],
+                  dtype: np.dtype, shape: Tuple[int, ...],
+                  name: str) -> List[np.ndarray]:
+    """The rows of each shard of a leaf, a view of the bytes read, once its
+    CRC has matched the manifest's; together they must make the leaf's
+    ``shape``."""
+    views = []
+    for sh, data, crc in read:
+        with obs.span("ckpt.crc32", bytes=len(data), ready=int(crc.done())):
+            got = crc.result()
+        if got != sh["crc32"]:
+            raise IOError(f"checksum mismatch in {sh['path']}")
+        with obs.span("ckpt.decode", bytes=len(data)):
+            views.append(_rows(data, dtype, shape, sh["path"]))
+    if _joined_shape(views) != shape:
+        raise IOError(f"the shards of {name} hold rows of shape "
+                      f"{_joined_shape(views)}, not {shape}")
+    return views
 
 
 def _put(views: List[np.ndarray], want: np.dtype,

@@ -2,6 +2,7 @@
 checkpoint restore through a cluster."""
 
 import itertools
+import time
 
 import numpy as np
 import pytest
@@ -162,7 +163,8 @@ def test_restore_spans_nest_as_the_read_path_does(saved):
                "datanode.read": {"client.fetch"},
                "ckpt.crc32": {"ckpt.restore"},
                "ckpt.decode": {"ckpt.restore"},
-               "ckpt.put": {"ckpt.restore"}}
+               "ckpt.put": {"ckpt.restore"},
+               "ckpt.crc32_work": {None}}
     for s in rec.spans:
         assert parent[s.id] in allowed[s.name], (s.name, parent[s.id])
     t = obs.totals(rec.spans)
@@ -230,3 +232,76 @@ def test_cluster_stats_are_the_same_with_recording_on_and_off(saved):
         return dict(mnt.client.stats)
 
     assert stats(False) == stats(True)
+
+
+class _SlowMount:
+    """A mount whose every read takes 50 ms more, as a shard's read over
+    the network takes several times its CRC."""
+
+    def __init__(self, mnt):
+        self._mnt = mnt
+
+    def read_file(self, path):
+        data = self._mnt.read_file(path)
+        time.sleep(0.05)
+        return data
+
+    def __getattr__(self, name):
+        return getattr(self._mnt, name)
+
+
+@pytest.fixture(scope="module")
+def saved_large():
+    """Four leaves of two 2 MiB shards each: ``zlib`` lets go of the
+    interpreter lock over buffers this size."""
+    cluster = CfsCluster(n_meta=4, n_data=6, extent_max_size=1024 * 1024)
+    cluster.create_volume("v", 3, 8)
+    rng = np.random.default_rng(1)
+    tree = {f"w{i}": rng.standard_normal((8, 2 ** 17)).astype(np.float32)
+            for i in range(4)}
+    CheckpointManager(cluster.mount("v"), "/ck", shards=2).save(1, tree)
+    return cluster, tree
+
+
+def test_crc_spans_time_the_wait_behind_the_next_read(saved_large):
+    """Each ``ckpt.crc32`` span is the restore's wait for one shard's CRC,
+    opened under ``ckpt.restore``, counting the shard's ``bytes`` and
+    ``ready``; every CRC but the last is done behind the next shard's read
+    before it is asked for, and the self times still split the restore."""
+    cluster, tree = saved_large
+    with obs.recording() as rec:
+        got, _ = CheckpointManager(_SlowMount(cluster.mount("v")),
+                                   "/ck").restore(tree)
+    by_id = {s.id: s for s in rec.spans}
+    crcs = [s for s in rec.spans if s.name == "ckpt.crc32"]
+    assert len(crcs) == 8
+    for s in crcs:
+        assert by_id[s.parent].name == "ckpt.restore"
+        assert set(s.counts) == {"bytes", "ready"}
+        assert s.counts["bytes"] > 2 * 2 ** 20
+    assert sum(s.counts["ready"] for s in crcs) >= len(crcs) - 1
+    t = obs.totals(rec.spans)
+    parts = t["ckpt.restore"]["self_seconds"] + sum(
+        t[n]["self_seconds"] for n in RESTORE_PARTS)
+    assert parts == pytest.approx(t["ckpt.restore"]["seconds"], rel=1e-9)
+    for k, v in tree.items():
+        assert got[k].tobytes() == v.tobytes()
+
+
+def test_crc_work_is_timed_on_the_worker(saved_large):
+    """The CRC's own work stays readable: one ``ckpt.crc32_work`` span per
+    shard, a root span of the worker thread (so it splits nothing of the
+    restore's), with the shard's ``bytes``, inside the restore's time."""
+    cluster, tree = saved_large
+    with obs.recording() as rec:
+        CheckpointManager(_SlowMount(cluster.mount("v")), "/ck").restore(tree)
+    restore, = [s for s in rec.spans if s.name == "ckpt.restore"]
+    work = [s for s in rec.spans if s.name == "ckpt.crc32_work"]
+    waits = [s for s in rec.spans if s.name == "ckpt.crc32"]
+    assert len(work) == len(waits) == 8
+    assert sorted(s.counts["bytes"] for s in work) == \
+        sorted(s.counts["bytes"] for s in waits)
+    for s in work:
+        assert s.parent is None and set(s.counts) == {"bytes"}
+        assert restore.start <= s.start and s.end <= restore.end
+        assert s.seconds > 0

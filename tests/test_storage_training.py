@@ -2,7 +2,9 @@
 deterministic replay, crash safety, hedged reads, elastic restore."""
 
 import dataclasses
+import itertools
 import json
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -383,6 +385,115 @@ def test_a_shard_in_the_unpadded_layout_still_restores(cluster, on_device):
     assert isinstance(got["a"], jax.Array)
     assert np.asarray(got["a"]).tobytes() == tree["a"].tobytes()
     assert _host_copy_bytes(rec) == tree["a"].nbytes
+
+
+class _LoggedMount:
+    """A mount that logs each shard file it has read, in order, into
+    ``log``, beside the ``put`` calls a test logs there."""
+
+    def __init__(self, mnt, log):
+        self._mnt, self.log = mnt, log
+
+    def read_file(self, path):
+        data = self._mnt.read_file(path)
+        if ".shard" in path:
+            self.log.append(("read", path))
+        return data
+
+    def __getattr__(self, name):
+        return getattr(self._mnt, name)
+
+
+def _logged_put(log, on_device):
+    def put(arr):
+        log.append(("put", arr.nbytes))
+        return jnp.asarray(arr) if on_device else arr
+    return put
+
+
+@pytest.mark.parametrize("on_device", [True, False])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("shards", [1, 2])
+def test_restore_reads_one_shard_ahead_of_the_last_put(cluster, shards,
+                                                       dtype, on_device):
+    """Each shard's CRC runs behind the next shard's read: a leaf's shards
+    reach ``put`` once every shard of it and the first of the next leaf
+    have been read (all of them after the last leaf), never later and
+    never sooner.  The leaves are bit for bit what ``bytes_to_tensor`` of
+    each shard, concatenated, gives, on the host and on the device, and
+    no thread outlives the restore."""
+    dt = np.dtype(jnp.bfloat16) if dtype == "bfloat16" else np.dtype(dtype)
+    mnt = cluster.mount("train")
+    base = f"/ck_ahead_{shards}_{dtype}_{on_device}"
+    tree = dict(_leaves(dt, seed=12),
+                e=np.random.RandomState(13).randn(8, 3).astype(dt))
+    CheckpointManager(mnt, base, shards=shards).save(1, tree)
+    manifest = json.loads(mnt.read_file(f"{base}/step_1/MANIFEST").decode())
+    like = (_on_device(tree) if on_device
+            else {k: np.zeros(v.shape, v.dtype) for k, v in tree.items()})
+    log = []
+    threads = threading.active_count()
+    got, _ = CheckpointManager(
+        _LoggedMount(cluster.mount("train"), log), base).restore(
+            like, put=_logged_put(log, on_device))
+    assert threading.active_count() == threads
+    # the flat shard order, and where each leaf's shards end in it
+    paths = [sh["path"] for k in sorted(tree)
+             for sh in manifest["tensors"][k]["shards"]]
+    ends = list(itertools.accumulate(
+        len(manifest["tensors"][k]["shards"]) for k in sorted(tree)))
+    assert [p for what, p in log if what == "read"] == paths
+    puts = [n for n, (what, _) in enumerate(log) if what == "put"]
+    assert len(puts) == len(paths)
+    for n, at in enumerate(puts):
+        end = next(e for e in ends if e > n)
+        read_before = sum(what == "read" for what, _ in log[:at])
+        assert read_before == min(end + 1, len(paths)), (paths[n], log)
+    for k, v in tree.items():
+        parts = [bytes_to_tensor(mnt.read_file(sh["path"]))
+                 for sh in manifest["tensors"][k]["shards"]]
+        want = np.concatenate(parts, 0) if len(parts) > 1 else parts[0]
+        assert isinstance(got[k], jax.Array if on_device else np.ndarray)
+        assert got[k].dtype == want.dtype == dt
+        assert got[k].shape == want.shape == v.shape
+        assert np.asarray(got[k]).tobytes() == want.tobytes() == v.tobytes()
+
+
+@pytest.mark.parametrize("on_device", [True, False])
+@pytest.mark.parametrize("shard", ["c.shard1", "e.shard1"])
+def test_a_flipped_bit_behind_the_next_read_stops_its_leaf_before_put(
+        cluster, shard, on_device):
+    """A bit flipped in the rows of a middle leaf's shard, or of the last
+    leaf's last shard (whose CRC has no later read to run behind), raises
+    IOError naming the shard; no shard of that leaf reaches ``put``, every
+    shard of the leaves before it did, and no thread outlives the
+    restore."""
+    mnt = cluster.mount("train")
+    base = f"/ck_flip_ahead_{shard}_{on_device}"
+    tree = dict(_leaves(np.float32, seed=14),
+                e=np.random.RandomState(15).randn(8, 3).astype(np.float32))
+    CheckpointManager(mnt, base, shards=2).save(1, tree)
+    path = f"{base}/step_1/{shard}"
+    at = mnt.stat(path)["size"] - 3
+    f = mnt.open(path, "r+")
+    f.seek(at)
+    byte = f.read(1)[0]
+    f.seek(at)
+    f.write(bytes([byte ^ 0x10]))
+    f.close()
+    like = (_on_device(tree) if on_device
+            else {k: np.zeros(v.shape, v.dtype) for k, v in tree.items()})
+    put = []
+    threads = threading.active_count()
+    with pytest.raises(IOError, match=f"checksum mismatch in {path}$"):
+        CheckpointManager(cluster.mount("train"), base).restore(
+            like, put=lambda arr: put.append(arr) or (
+                jnp.asarray(arr) if on_device else arr))
+    assert threading.active_count() == threads
+    before = "abcd"[: "abcde".index(shard[0])]
+    want = [r for k in before
+            for r in (np.split(tree[k], 2) if k in "ace" else [tree[k]])]
+    assert [p.tobytes() for p in put] == [r.tobytes() for r in want]
 
 
 def test_elastic_restore_different_shard_count(cluster, data_volume):
