@@ -6,7 +6,7 @@ tests.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Optional
+from typing import List, Optional, Tuple
 
 __all__ = ["ArchConfig"]
 
@@ -35,6 +35,20 @@ class ArchConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    mla_nope: bool = False                  # no RoPE: the rope columns stay
+    #                                         in every shape, unrotated
+    # Kimi Delta Attention (KDA, Kimi Linear), on when kda_layers is set: the
+    # 1-based numbers of the published layers that are KDA (gated delta-rule
+    # linear attention, kda_heads x kda_head_dim, a causal conv of kda_conv
+    # over time, decay and output gates of rank kda_gate_rank); every other
+    # layer is latent attention.  Prefill runs the chunked form, kda_chunk
+    # tokens a chunk
+    kda_layers: Tuple[int, ...] = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    kda_gate_rank: int = 0
+    kda_chunk: int = 64
 
     # MoE
     n_experts: int = 0
@@ -75,9 +89,21 @@ class ArchConfig:
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
 
+    def layer_kinds(self) -> List[str]:
+        """Each layer's attention in order: "kda" or "attn"."""
+        return ["kda" if i + 1 in self.kda_layers else "attn"
+                for i in range(self.n_layers)]
+
     # ---- parameter counting ------------------------------------------------
-    def _attn_params(self) -> int:
+    def _attn_params(self, kind: str = "attn") -> int:
         d, H = self.d_model, self.n_heads
+        if kind == "kda":
+            hk, r = self.kda_heads * self.kda_head_dim, self.kda_gate_rank
+            return (d * 3 * hk + self.kda_conv * 3 * hk   # wqkv, conv
+                    + d * self.kda_heads + self.kda_heads  # wb, A_log
+                    + d * r + r * hk + hk                 # wf_a, wf_b, dt_bias
+                    + d * r + r * hk + hk                 # wg_a, wg_b, g_bias
+                    + self.kda_head_dim + hk * d)         # o_norm, wo
         if self.kv_lora_rank:
             r, rope = self.kv_lora_rank, self.qk_rope_head_dim
             return (d * H * (self.qk_nope_head_dim + rope)   # wq
@@ -118,8 +144,10 @@ class ArchConfig:
         if self.family == "moe":
             held = self.n_experts_held or self.n_experts
             k = self.first_k_dense
-            return (emb + d + k * (attn + mlp_dense + norms)
-                    + (L - k) * (attn + self._moe_layer_params(held) + norms))
+            attn_all = sum(self._attn_params(kind)
+                           for kind in self.layer_kinds())
+            return (emb + d + attn_all + L * norms + k * mlp_dense
+                    + (L - k) * self._moe_layer_params(held))
         return emb + d + L * (attn + mlp_dense + norms)
 
     # ---- reduced config for CPU smoke tests --------------------------------
@@ -128,7 +156,8 @@ class ArchConfig:
         mla = self.kv_lora_rank > 0
         return replace(
             self,
-            n_layers=min(self.n_layers, 2 if not self.attn_every else 4),
+            n_layers=min(self.n_layers,
+                         4 if self.attn_every or self.kda_layers else 2),
             d_model=128,
             n_heads=4,
             n_kv_heads=min(4, max(1, self.n_kv_heads * 4 // self.n_heads)),
@@ -141,6 +170,10 @@ class ArchConfig:
             qk_nope_head_dim=32 if mla else 0,
             qk_rope_head_dim=16 if mla else 0,
             v_head_dim=32 if mla else 0,
+            kda_heads=4 if self.kda_layers else 0,
+            kda_head_dim=32 if self.kda_layers else 0,
+            kda_gate_rank=32 if self.kda_layers else 0,
+            kda_chunk=16 if self.kda_layers else self.kda_chunk,
             d_expert=64 if self.n_experts else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=32,

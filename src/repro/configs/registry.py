@@ -6,6 +6,7 @@ from .arctic_480b import CONFIG as _arctic
 from .base import ArchConfig
 from .chameleon_34b import CONFIG as _chameleon
 from .codeqwen15_7b import CONFIG as _codeqwen
+from .kimi_linear_48b_a3b import CONFIG as _kimi_linear
 from .minicpm_2b import CONFIG as _minicpm
 from .mixtral_8x22b import CONFIG as _mixtral
 from .moonlight_16b_a3b import CONFIG as _moonlight
@@ -20,6 +21,7 @@ ARCHS: Dict[str, ArchConfig] = {
     for c in [
         _codeqwen, _phi3, _minicpm, _qwen32, _rwkv6,
         _arctic, _mixtral, _zamba2, _musicgen, _chameleon, _moonlight,
+        _kimi_linear,
     ]
 }
 

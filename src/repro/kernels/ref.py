@@ -415,6 +415,118 @@ def mamba2_ssd(x, dt, A, B, C, state, chunk: int = 128):
     return y[:, :t].astype(x.dtype), state
 
 
+# ================================================= KDA (gated delta rule)
+#
+# Kimi Delta Attention (Kimi Linear, arXiv:2510.26692), per head: a state
+# S [K, V] decayed per key channel, then corrected toward each new value
+# along its key (the delta rule):
+#
+#   S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1} + b_t k_t v_t^T
+#   o_t = S_t^T q_t
+#
+# q, k, v [B, T, H, K|V]; g [B, T, H, K], the log decays (<= 0); b
+# [B, T, H] in (0, 1); state [B, H, K, V].  The caller scales q.  All in
+# float32 at "highest" matmul precision.
+
+_HI = lax.Precision.HIGHEST
+
+
+def kda_step(S, q, k, v, g, b):
+    """One token: S [B,H,K,V]; q, k, g [B,H,K]; v [B,H,V]; b [B,H].
+    Returns (S_t, o_t [B,H,V])."""
+    S = jnp.exp(g)[..., None] * S
+    err = v - jnp.einsum("bhk,bhkv->bhv", k, S, precision=_HI)
+    S = S + jnp.einsum("bhk,bhv->bhkv", b[..., None] * k, err, precision=_HI)
+    return S, jnp.einsum("bhk,bhkv->bhv", q, S, precision=_HI)
+
+
+def kda_naive(q, k, v, g, b, state):
+    """Per-token KDA recurrence oracle.  Returns (o [B,T,H,V], state)."""
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, b))
+    state, o = lax.scan(lambda S, x: kda_step(S, *x), state, xs)
+    return jnp.moveaxis(o, 0, 1), state
+
+
+def kda_chunked(q, k, v, g, b, state, chunk: int = 64, sub: int = 4):
+    """Chunked KDA (the WY/UT form of the gated delta rule, as in Gated
+    DeltaNet, arXiv:2412.06464, with a decay per key channel).
+
+    Within a chunk, with G the cumulative log decay from its start:
+      A[i,j] = sum_c k_i k_j exp(G_i - G_j) (j < i), M[i,j] the same with
+      q_i (j <= i); (I + Diag(b) A) [U0 | W] = [b v | b exp(G) k];
+      U = U0 - W S;  O = (q exp(G)) S + M U;
+      S' = exp(G_last) S + (k exp(G_last - G))^T U.
+    Every decay is exp of a difference of cumulative log decays that is
+    <= 0, so nothing overflows however strong the decay: inside a
+    sub-chunk of ``sub`` tokens exactly, per pair; across sub-chunks p > s
+    as exp(G_i - G_first(p)) exp(G_first(p) - G_last(s)) exp(G_last(s) -
+    G_j).  The chunks run in sequence (``kda.chunk``: the intra-chunk
+    solve; ``kda.state``: the pass of the state), holding one chunk's
+    [C, sub, K] terms at a time."""
+    bsz, t, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = (-t) % chunk
+    if pad:
+        # padded tokens: zero key and b (no update), zero log decay
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
+    n, ns = q.shape[1] // chunk, chunk // sub
+
+    def split(a):                       # [B, T, H, ...] -> [n, B, H, C, ...]
+        a = a.reshape(bsz, n, chunk, h, *a.shape[3:]).astype(jnp.float32)
+        return jnp.moveaxis(jnp.moveaxis(a, 1, 0), 3, 2)
+
+    tri = jnp.arange(sub)[:, None] >= jnp.arange(sub)[None, :]
+    later = jnp.arange(ns)[:, None] > jnp.arange(ns)[None, :]
+    strict = jnp.arange(chunk)[:, None] > jnp.arange(chunk)[None, :]
+    same = jnp.eye(ns, dtype=jnp.float32)[:, None, :, None]
+
+    def chunk_step(S, xs):
+        qc, kc, vc, gc, bc = xs                  # [B,H,C,K|V|1]
+        with jax.named_scope("kda.chunk"):
+            G = jnp.cumsum(gc, axis=2)
+            Gs = G.reshape(bsz, h, ns, sub, dk)
+            first, last = Gs[:, :, :, :1], Gs[:, :, :, -1:]
+            ks = kc.reshape(bsz, h, ns, sub, dk)
+            x = jnp.stack([qc.reshape(ks.shape), ks])   # q rows, k rows
+            # across sub-chunks p > s
+            pair = jnp.exp(jnp.where(later[..., None],
+                                     first[:, :, :, None, 0]
+                                     - last[:, :, None, :, 0], -jnp.inf))
+            off = jnp.einsum("xbhpik,bhpsk,bhsjk->xbhpisj",
+                             x * jnp.exp(Gs - first), pair,
+                             ks * jnp.exp(last - Gs), precision=_HI)
+            # inside a sub-chunk, j <= i
+            d = Gs[:, :, :, :, None] - Gs[:, :, :, None, :]
+            e = jnp.exp(jnp.where(tri[..., None], d, -jnp.inf))
+            diag = jnp.einsum("xbhpik,bhpijk,bhpjk->xbhpij", x, e, ks,
+                              precision=_HI)
+            mat = (off + diag[..., None, :] * same).reshape(
+                2, bsz, h, chunk, chunk)
+            M, A = mat[0], jnp.where(strict, mat[1], 0.0)
+            gam = jnp.exp(G)
+            rhs = jnp.concatenate([bc * vc, bc * gam * kc], -1)
+            sol = jax.scipy.linalg.solve_triangular(
+                bc * A, rhs, lower=True, unit_diagonal=True)
+        with jax.named_scope("kda.state"):
+            u = sol[..., :dv] - jnp.einsum("bhck,bhkv->bhcv", sol[..., dv:],
+                                           S, precision=_HI)
+            o = (jnp.einsum("bhck,bhkv->bhcv", qc * gam, S, precision=_HI)
+                 + jnp.einsum("bhij,bhjv->bhiv", M, u, precision=_HI))
+            g_last = G[:, :, -1]
+            S = (jnp.exp(g_last)[..., None] * S
+                 + jnp.einsum("bhck,bhcv->bhkv",
+                              kc * jnp.exp(g_last[:, :, None] - G), u,
+                              precision=_HI))
+        return S, o
+
+    state, o = lax.scan(chunk_step, state.astype(jnp.float32),
+                        tuple(split(a) for a in (q, k, v, g, b[..., None])))
+    o = jnp.moveaxis(o, 0, 1).swapaxes(2, 3).reshape(bsz, n * chunk, h, dv)
+    return o[:, :t], state
+
+
 # ================================================================ checksum
 
 CHECKSUM_PRIME = jnp.uint32(4_294_967_291)  # largest 32-bit prime

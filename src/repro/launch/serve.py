@@ -52,6 +52,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     params = api.init(jax.random.PRNGKey(0), jnp.float32)
     srv = BatchServer(cfg, params, batch=args.batch,
                       smax=max_prompt + args.max_new)
+    if srv.recurrent:       # served one prompt length a wave
+        min_prompt = max_prompt
     reqs = make_requests(cfg.vocab, args.requests, min_prompt, max_prompt,
                          args.max_new)
     done = srv.serve(reqs)

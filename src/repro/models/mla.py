@@ -6,7 +6,10 @@ Queries come straight from the hidden state (no query latent):
 ``wkv_a`` [d, rank+rope] gives the latent (RMSNorm'd by ``kv_norm``) and
 one RoPE key shared by every head; ``wkv_b`` [rank, H*(nope+v)] lifts the
 latent to each head's key (``nope``) and value (``v``) columns.  Scores
-are q_nope.k_nope + q_rope.k_rope over sqrt(nope + rope).
+are q_nope.k_nope + q_rope.k_rope over sqrt(nope + rope).  With
+``mla_nope`` (Kimi Linear) nothing is rotated: the rope columns are plain
+query and key columns, and attention sees no position but the causal
+order.
 
 The cache holds what a token adds: its normed latent and its RoPE'd key,
 ``[B, S, rank + rope]``.  Prefill lifts the latent to per-head keys and
@@ -41,14 +44,18 @@ def init_mla(cfg: ArchConfig, key, dtype=jnp.bfloat16) -> Params:
 def _project(cfg: ArchConfig, p: Params, x: jnp.ndarray,
              positions: jnp.ndarray):
     """x [B, T, d] -> q_nope [B,T,H,nope], q_rope [B,T,H,rope] and the
-    cache entry [B, T, rank + rope] (normed latent, RoPE'd shared key)."""
+    cache entry [B, T, rank + rope] (normed latent, RoPE'd shared key;
+    unrotated under ``mla_nope``)."""
     b, t, _ = x.shape
     H, r, nope = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
     q = jnp.einsum("btd,dh->bth", x, p["wq"]).reshape(b, t, H, -1)
-    q_rope = rope(q[..., nope:], positions, cfg.rope_theta)
+    # under mla_nope the rope columns stay, unrotated
+    q_rope = (q[..., nope:] if cfg.mla_nope
+              else rope(q[..., nope:], positions, cfg.rope_theta))
     kv = jnp.einsum("btd,dr->btr", x, p["wkv_a"])
     c = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
-    k_rope = rope(kv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = (kv[..., r:] if cfg.mla_nope else
+              rope(kv[..., None, r:], positions, cfg.rope_theta)[:, :, 0])
     return q[..., :nope], q_rope, jnp.concatenate([c, k_rope], -1)
 
 

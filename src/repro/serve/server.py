@@ -40,6 +40,11 @@ class BatchServer:
         self._prefill = jax.jit(
             lambda p, t: self.api.prefill(p, t, smax, "bfloat16", False))
         self._decode = jax.jit(self.api.decode)
+        # a recurrent layer's state takes in every token of the row, so a
+        # left pad would enter it: such a model is served one prompt length
+        # a wave
+        self.recurrent = cfg.family in ("ssm", "hybrid") or bool(
+            cfg.kda_layers)
 
     def serve(self, requests: List[Request]) -> List[Request]:
         """Serve a queue of requests through fixed batch slots."""
@@ -48,6 +53,12 @@ class BatchServer:
         while queue:
             wave = queue[: self.batch]
             queue = queue[self.batch :]
+            lengths = {len(r.prompt) for r in wave}
+            if self.recurrent and len(lengths) > 1:
+                raise ValueError(
+                    f"{self.cfg.name} has recurrent layers, whose state would "
+                    f"take in the left pads of a wave of mixed prompt lengths "
+                    f"{sorted(lengths)}: queue prompts of one length a wave")
             with obs.span("server.wave", slots=len(wave)) as sp:
                 # pad the wave to full batch with a dummy
                 while len(wave) < self.batch:
@@ -57,6 +68,7 @@ class BatchServer:
                 for i, r in enumerate(wave):
                     toks[i, max_p - len(r.prompt):] = r.prompt  # left-pad
                 logits, cache = self._prefill(self.params, jnp.asarray(toks))
+                _count_state(sp, cache)
                 busiest = _count_experts(sp, cache, 0)
                 cur = jnp.argmax(logits[:, -1, : self.cfg.vocab], -1).astype(
                     jnp.int32)
@@ -92,3 +104,16 @@ def _count_experts(sp, cache, busiest: Optional[int]) -> Optional[int]:
     rows, most = (int(v) for v in np.asarray(cache["expert_rows"]))
     sp.add(expert_rows=rows)
     return max(busiest or 0, most)
+
+
+def _count_state(sp, cache) -> None:
+    """For a cache that holds two kinds of state (a hybrid's recurrent
+    ``kda_*`` state beside its latent cache), adds the bytes of each to the
+    wave's span: ``state_bytes`` and ``cache_bytes``."""
+    if "kda_state" not in cache:
+        return
+    kinds = {True: 0, False: 0}
+    for k, v in cache.items():
+        if k != "expert_rows":
+            kinds[k.startswith("kda_")] += v.nbytes
+    sp.add(state_bytes=kinds[True], cache_bytes=kinds[False])

@@ -141,3 +141,34 @@ def test_moonlight_prefill_fits_one_v5e(one_chip):
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert peak < V5E_HBM_BYTES, f"prefill needs {peak} bytes"
+
+
+def test_kimi_linear_prefill_fits_one_v5e(one_chip):
+    """The serving prefill of the benchmarked Kimi-Linear share (published
+    layers 1-20: 15 KDA and 5 latent-attention layers, 8 of 256 experts
+    held, bf16) for a wave of 8 prompts of 4,096 tokens, as
+    ``BatchServer`` jits it: the weights, both caches (the KDA state and
+    conv tail, the latent cache) and the prefill's temporaries fit one
+    chip's 16 GiB."""
+    import dataclasses
+    from repro.serve.server import BatchServer
+    cfg = dataclasses.replace(get_arch("kimi-linear-48b-a3b"), n_layers=20,
+                              n_experts_held=8)
+    on_chip = lambda s: jax.ShapeDtypeStruct(  # noqa: E731
+        s.shape, s.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: get_model(cfg).init(jax.random.PRNGKey(0), jnp.bfloat16)))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 2 * 2_778_650_464
+    srv = BatchServer(cfg, None, batch=8, smax=4097)
+    tokens = on_chip(jax.ShapeDtypeStruct((8, 4096), jnp.int32))
+    lowered = srv._prefill.lower(params, tokens)
+    cache = lowered.out_info[1]
+    assert cache["kda_state"].shape == (15, 8, 32, 128, 128)
+    assert cache["kda_state"].dtype == jnp.float32
+    assert cache["kda_conv"].shape == (15, 8, 3, 3 * 32 * 128)
+    assert cache["ckv"].shape == (5, 8, 4097, 576)
+    mem = lowered.compile().memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert peak < V5E_HBM_BYTES, f"prefill needs {peak} bytes"
